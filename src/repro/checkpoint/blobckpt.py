@@ -28,11 +28,16 @@ tolerance substrate:
   deployment with content-addressed dedup matches equal pages (branch
   twins, re-written checkpoints) without hashing anything twice.
 
-Blob traffic is plain numpy/bytes on the host side: device arrays are
-pulled once per leaf with ``jax.device_get`` and all dirty runs of a
-save ride one batched ``write_many`` (a real multi-host deployment
-would hand each host its own leaf shards; the interface is per-leaf so
-that change is local).
+Page digests are taken where each leaf lives: the kernels bitcast the
+device-resident leaf in place, and a leaf sharded over a mesh is
+digested by every device of that mesh, each over its own share of the
+pages.  Blob traffic is plain numpy/bytes on the host side: a leaf with
+a dirty page is pulled once with ``jax.device_get`` (a clean leaf never
+leaves the device) and all dirty runs of a save ride one batched
+``write_many`` (a real multi-host deployment would hand each host its
+own leaf shards; the interface is per-leaf so that change is local).
+Restored leaves come back as numpy, for the caller to place with its
+shardings.
 """
 
 from __future__ import annotations
@@ -45,7 +50,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.blob import BlobClient
 from repro.core.version_manager import RetiredVersion, VersionUnpublished
@@ -66,16 +70,37 @@ class CheckpointStats:
         return 1.0 - (self.pages_written / max(self.pages_total, 1))
 
 
-def _flatten_with_paths(tree) -> List[Tuple[str, Any]]:
+def flatten_with_paths(tree) -> List[Tuple[str, Any]]:
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
     out = []
     for path, leaf in flat:
         key = "/".join(
             str(p.key) if hasattr(p, "key") else str(p.idx) for p in path
         )
-        out.append((key, leaf))
+        out.append((key, leaf if hasattr(leaf, "dtype") else np.asarray(leaf)))
     out.sort(key=lambda kv: kv[0])
     return out
+
+
+def _nbytes(leaf) -> int:
+    return int(np.prod(np.shape(leaf))) * np.dtype(leaf.dtype).itemsize
+
+
+def header_pages_for(like, psize: int) -> int:
+    """Header pages (commit pointer + manifest) for a state shaped ``like``.
+
+    The manifest keeps 16 hex digits of digest per page plus one record
+    per leaf, so its size follows the page count.  The bound is taken on
+    the uncompressed JSON (zlib adds at most a few bytes per 16 KiB
+    block), with 4 KiB left for the top-level fields and ``extra``.
+    """
+    leaves = flatten_with_paths(like)
+    n_pages = sum(-(-max(_nbytes(leaf), 1) // psize) for _, leaf in leaves)
+    per_leaf = sum(128 + 2 * len(path) + 16 * len(np.shape(leaf))
+                   for path, leaf in leaves)
+    manifest = 4096 + per_leaf + 16 * n_pages
+    record = 8 + manifest + manifest // 1000 + 64
+    return 1 + -(-record // psize)
 
 
 class BlobCheckpointer:
@@ -105,17 +130,14 @@ class BlobCheckpointer:
     # ------------------------------------------------------------------- save
     def save(self, state, step: int, extra: Optional[Dict] = None) -> CheckpointStats:
         """Write an incremental checkpoint; returns sharing stats."""
-        leaves = _flatten_with_paths(state)
+        leaves = flatten_with_paths(state)
         psz = self.psize
 
         # -- layout: leaf offsets page-aligned after the header region --
         offset = self.header_bytes
         layout: Dict[str, Tuple[int, int]] = {}
-        arrays: Dict[str, np.ndarray] = {}
         for path, leaf in leaves:
-            arr = np.asarray(jax.device_get(leaf))
-            arrays[path] = arr
-            nbytes = max(arr.nbytes, 1)
+            nbytes = max(_nbytes(leaf), 1)
             layout[path] = (offset, nbytes)
             offset += -(-nbytes // psz) * psz
         total = offset
@@ -145,21 +167,26 @@ class BlobCheckpointer:
         # index matches on exactly these digests, nothing hashes twice
         dirty_digests: List[List[Tuple[int, int]]] = []
         for path, leaf in leaves:
-            arr = arrays[path]
             off, nbytes = layout[path]
-            raw = arr.tobytes()
-            padded = raw + b"\0" * ((-len(raw)) % 4)
-            dg = np.asarray(kops.page_digest(
-                jnp.asarray(np.frombuffer(padded, dtype=np.uint8)), page_bytes=psz,
-            ))
-            new_digests[path] = dg
+            # digest where the leaf lives (its device, or its mesh)
+            dg_dev = kops.page_digest(leaf, page_bytes=psz)
             old = self._digests.get(path)
-            if layout_changed or old is None or old.shape != dg.shape:
-                dirty = np.ones(dg.shape[0], dtype=bool)
+            if layout_changed or old is None or old.shape != dg_dev.shape:
+                dirty = np.ones(dg_dev.shape[0], dtype=bool)
             else:
-                dirty = np.asarray(kops.delta_mask(
-                    jax.numpy.asarray(dg), jax.numpy.asarray(old)
-                ))
+                dirty = np.asarray(kops.delta_mask(dg_dev, old))
+            dg = np.asarray(dg_dev)
+            new_digests[path] = dg
+            manifest_leaves.append({
+                "path": path,
+                "shape": list(np.shape(leaf)),
+                "dtype": str(np.dtype(leaf.dtype)),
+                "offset": off,
+                "nbytes": nbytes,
+            })
+            if not dirty.any():
+                continue  # clean leaf: nothing leaves the device
+            raw = np.ascontiguousarray(jax.device_get(leaf)).reshape(-1).view(np.uint8)
             # write contiguous dirty page runs, zero-padded to full pages:
             # page-aligned writes are BlobSeer's fast path (no boundary
             # merging) and keep blob growth contiguous
@@ -173,7 +200,7 @@ class BlobCheckpointer:
                 while j < n_pages and dirty[j]:
                     j += 1
                 lo = i * psz
-                chunk = raw[lo : j * psz]
+                chunk = raw[lo : j * psz].tobytes()
                 pad = (j - i) * psz - len(chunk)
                 if pad:
                     chunk = chunk + b"\0" * pad
@@ -183,13 +210,6 @@ class BlobCheckpointer:
                 written_bytes += len(chunk)
                 pages_written += j - i
                 i = j
-            manifest_leaves.append({
-                "path": path,
-                "shape": list(arr.shape),
-                "dtype": str(arr.dtype),
-                "offset": off,
-                "nbytes": nbytes,
-            })
 
         if dirty_writes:
             self.client.write_many(self.blob_id, dirty_writes,

@@ -18,10 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _stochastic_round_int8(x: jax.Array, scale: jax.Array, rng: jax.Array) -> jax.Array:

@@ -24,21 +24,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# jax renamed check_rep -> check_vma; pass whichever this version takes
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else "check_rep"
-)
 
 AXIS = "model"
 
@@ -135,7 +122,7 @@ def sharded_decode_attention(
             P(dp_spec, None, AXIS, None),
             P(AXIS),
         ),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     out, ck, cv, cpos = fn(q, cache["k"], cache["v"], cache["pos"],
                            k_new, v_new, positions)

@@ -2,10 +2,11 @@
 
 Callers use these wrappers, never the kernels directly:
 
-* on TPU the Pallas kernels run compiled;
-* on CPU (this container) the pure-jnp references run under jit, and the
-  Pallas kernels can be forced through the interpreter with
-  ``REPRO_PALLAS=interpret`` (the kernel-vs-oracle test path).
+* on a TPU the Pallas kernels always run compiled;
+* elsewhere the pure-jnp references run under jit, unless
+  ``REPRO_PALLAS=interpret`` sends the Pallas kernels through the
+  interpreter (the kernel-vs-oracle test path).  It is the variable's
+  only value, and a TPU ignores it.
 
 Every wrapper normalizes shapes/dtypes so the Pallas and reference paths
 see bit-identical inputs — the correctness contract the tests assert.
@@ -15,9 +16,14 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import Optional
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels import ref as _ref
 from repro.kernels.delta_mask import delta_mask_pallas
@@ -32,19 +38,17 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def use_pallas() -> bool:
-    mode = os.environ.get("REPRO_PALLAS", "auto")
-    if mode == "off":
-        return False
-    if mode in ("on", "interpret"):
-        return True
-    return _backend() == "tpu"
-
-
 def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS") == "interpret":
-        return True
-    return _backend() != "tpu"
+    if _backend() == "tpu":
+        return False
+    mode = os.environ.get("REPRO_PALLAS", "")
+    if mode not in ("", "interpret"):
+        raise ValueError(f"REPRO_PALLAS={mode!r}: the only value is 'interpret'")
+    return mode == "interpret"
+
+
+def use_pallas() -> bool:
+    return _backend() == "tpu" or _interpret()
 
 
 # ---------------------------------------------------------------------------
@@ -53,26 +57,75 @@ def _interpret() -> bool:
 
 
 def as_page_words(data: jax.Array, page_bytes: int) -> jax.Array:
-    """Reinterpret a flat array as (n_pages, words) u32, zero-padded.
+    """Reinterpret an array's bytes as (n_pages, words) u32, zero-padded.
 
     The canonical digest domain: bytes are padded to a whole number of
     ``page_bytes`` pages and each page to a multiple of
     ``DIGEST_BLOCK_WORDS`` 32-bit words, identically for both backends.
+    Words are built from the element type directly, so a device-resident
+    leaf is fingerprinted in place.  Narrow elements are packed by
+    strided lane slices of each page: a trailing axis of 2 or 4 would be
+    padded to a full 128-lane tile on a TPU.
     """
-    assert page_bytes % 4 == 0
     flat = data.reshape(-1)
-    as_bytes = jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
-    pad = (-as_bytes.shape[0]) % page_bytes
+    itemsize = flat.dtype.itemsize
+    assert page_bytes % max(itemsize, 4) == 0
+    pad = (-flat.shape[0] * itemsize) % page_bytes // itemsize
     if pad:
-        as_bytes = jnp.pad(as_bytes, (0, pad))
-    n_pages = as_bytes.shape[0] // page_bytes
-    words = jax.lax.bitcast_convert_type(
-        as_bytes.reshape(n_pages, page_bytes // 4, 4), jnp.uint32
-    )
+        flat = jnp.pad(flat, (0, pad))
+    if itemsize >= 4:
+        words = jax.lax.bitcast_convert_type(flat, jnp.uint32)
+    else:
+        per = 4 // itemsize
+        bits = jax.lax.bitcast_convert_type(flat, jnp.dtype(f"uint{8 * itemsize}"))
+        bits = bits.reshape(-1, page_bytes // itemsize)
+        words = bits[:, 0::per].astype(jnp.uint32)
+        for k in range(1, per):
+            words |= bits[:, k::per].astype(jnp.uint32) << (8 * itemsize * k)
+    words = words.reshape(-1, page_bytes // 4)
     word_pad = (-words.shape[1]) % DIGEST_BLOCK_WORDS
     if word_pad:
         words = jnp.pad(words, ((0, 0), (0, word_pad)))
     return words
+
+
+def _device_operand(data):
+    """Host arrays go to the device as raw bytes, whatever their dtype.
+
+    ``jnp.asarray`` would narrow a 64-bit host array to 32 bits, and the
+    digest must cover the bytes the checkpoint writes.
+    """
+    if isinstance(data, (np.ndarray, np.generic)):
+        return np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+    return data
+
+
+def _mesh_of(x) -> Optional[Mesh]:
+    """A 1-D mesh over the devices of a multi-device array, in mesh order."""
+    sharding = getattr(x, "sharding", None)
+    if sharding is None or len(sharding.device_set) == 1:
+        return None
+    if not isinstance(sharding, NamedSharding):
+        raise TypeError(f"cannot split pages over a {type(sharding).__name__}")
+    return Mesh(sharding.mesh.devices.reshape(-1), ("pages",))
+
+
+def _over_pages(kernel, mesh: Optional[Mesh], *arrays: jax.Array) -> jax.Array:
+    """Run a per-page kernel, its pages split over every device of ``mesh``.
+
+    A Mosaic kernel cannot be partitioned by the compiler, so on a mesh
+    each device runs it over its own contiguous share of the pages; the
+    (small) per-page result comes back replicated on the mesh.
+    """
+    if mesh is None:
+        return kernel(*arrays)
+    n = arrays[0].shape[0]
+    pad = (-n) % mesh.size
+    arrays = [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) for a in arrays]
+    spec = P("pages")
+    out = shard_map(kernel, mesh=mesh, in_specs=(spec,) * len(arrays),
+                    out_specs=spec, check_vma=False)(*arrays)
+    return jax.lax.with_sharding_constraint(out[:n], NamedSharding(mesh, P()))
 
 
 @functools.partial(jax.jit, static_argnames=("page_bytes",))
@@ -80,14 +133,19 @@ def _page_digest_ref(data: jax.Array, page_bytes: int) -> jax.Array:
     return _ref.ref_page_digest(as_page_words(data, page_bytes))
 
 
+@functools.partial(jax.jit, static_argnames=("page_bytes", "interpret", "mesh"))
+def _page_digest_kernel(data, page_bytes, interpret, mesh):
+    kernel = functools.partial(page_digest_pallas, block_w=DIGEST_BLOCK_WORDS,
+                               interpret=interpret)
+    return _over_pages(kernel, mesh, as_page_words(data, page_bytes))
+
+
 def page_digest(data: jax.Array, page_bytes: int = 64 * 1024) -> jax.Array:
     """Digest device-resident data as (n_pages, 2) u32 fingerprints."""
     if use_pallas():
-        words = as_page_words(data, page_bytes)
-        return page_digest_pallas(
-            words, block_w=DIGEST_BLOCK_WORDS, interpret=_interpret()
-        )
-    return _page_digest_ref(data, page_bytes)
+        return _page_digest_kernel(_device_operand(data), page_bytes,
+                                   _interpret(), _mesh_of(data))
+    return _page_digest_ref(_device_operand(data), page_bytes)
 
 
 @jax.jit
@@ -95,10 +153,17 @@ def _delta_mask_ref(new_digest: jax.Array, old_digest: jax.Array) -> jax.Array:
     return _ref.ref_delta_mask(new_digest, old_digest)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret", "mesh"))
+def _delta_mask_kernel(new_digest, old_digest, interpret, mesh):
+    kernel = functools.partial(delta_mask_pallas, interpret=interpret)
+    return _over_pages(kernel, mesh, new_digest, old_digest) != 0
+
+
 def delta_mask(new_digest: jax.Array, old_digest: jax.Array) -> jax.Array:
     """(n,) bool — pages whose digest changed since the last checkpoint."""
     if use_pallas():
-        return delta_mask_pallas(new_digest, old_digest, interpret=_interpret()) != 0
+        return _delta_mask_kernel(new_digest, old_digest, _interpret(),
+                                  _mesh_of(new_digest))
     return _delta_mask_ref(new_digest, old_digest)
 
 

@@ -22,6 +22,8 @@ evaluated blockwise Horner-style over word-blocks of size ``block_w``::
 TPU adaptation notes:
 
 * uint32 VPU arithmetic wraps mod 2^32 natively — no emulation needed;
+  only the row reduction runs on the int32 bitcast, since Mosaic does
+  not reduce over unsigned integers;
 * pages tile the sublane axis (8) and words the lane axis (128), so a
   (page_tile, block_w) = (8, 512) block is four perfectly aligned
   (8, 128) vregs;
@@ -59,6 +61,16 @@ def _block_mults(block_w: int) -> tuple[int, int]:
     return tuple(out)
 
 
+def _wrapping_row_sum(x: jax.Array) -> jax.Array:
+    """Row sums of a u32 block mod 2^32.
+
+    Mosaic has no reduction over unsigned integers, so the sum runs on
+    the int32 bitcast: two's-complement addition wraps to the same bits.
+    """
+    s = jax.lax.bitcast_convert_type(x, jnp.int32).sum(axis=1, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(s, U32)
+
+
 def _digest_kernel(x_ref, w_ref, o_ref, acc_ref, *, block_mults):
     """Grid: (page_tiles, word_blocks); word_blocks is sequential."""
     j = pl.program_id(1)
@@ -70,8 +82,8 @@ def _digest_kernel(x_ref, w_ref, o_ref, acc_ref, *, block_mults):
     x = x_ref[...] + U32(DIGEST_SALT)          # (PT, BW)
     w = w_ref[...]                              # (2, BW)
     # poly over this block for both multipliers: (PT, 2)
-    poly0 = (x * w[0][None, :]).sum(axis=1, dtype=U32)
-    poly1 = (x * w[1][None, :]).sum(axis=1, dtype=U32)
+    poly0 = _wrapping_row_sum(x * w[0][None, :])
+    poly1 = _wrapping_row_sum(x * w[1][None, :])
     carry0 = acc_ref[:, 0] * U32(block_mults[0]) + poly0
     carry1 = acc_ref[:, 1] * U32(block_mults[1]) + poly1
     acc_ref[...] = jnp.stack([carry0, carry1], axis=1)

@@ -1,4 +1,7 @@
 import os
+# a CPU tool: 512 virtual host devices, and never the chip, so that a
+# parent process that holds the chip can still run it
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -68,7 +71,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, strategy: str,
         t_compile = time.time() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        ca = H.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         txt = compiled.as_text()
         coll = H.collective_stats(txt)
         n_chips = mesh.size

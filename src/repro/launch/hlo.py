@@ -22,16 +22,6 @@ HBM_BW = 819e9           # bytes/s per chip
 ICI_BW = 50e9            # bytes/s per link
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    Older jax (< 0.5) returns a one-element list of per-computation
-    dicts; newer jax returns the dict directly.  Normalize to a dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -4,21 +4,26 @@ Defined as functions (never module-level constants) so importing this
 module never touches JAX device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before its
 first import, and smoke tests must keep seeing one device.
+
+Every axis is ``Auto``: the model code places arrays through
+``with_sharding_constraint`` and logical rules, which ``Explicit`` axes
+(``jax.make_mesh``'s default) reject.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes) -> Mesh:
     """Arbitrary mesh for tests / local runs (e.g. ((1,1),("data","model")))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import BlobCheckpointer
+from repro.checkpoint.blobckpt import header_pages_for
 from repro.core import BlobSeerService
 from repro.data import ByteTokenizer, CorpusWriter, ShardedReader
 
@@ -38,6 +39,23 @@ def test_save_restore_roundtrip(ckpt_env):
     got = ck.restore(jax.eval_shape(lambda: s))
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(s)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_header_sized_from_state_holds_its_manifest(ckpt_env):
+    # 4096 pages of 1 KiB: their digests outgrow a 16-page header region
+    svc, c = ckpt_env
+    psize = 1024
+    s = {"w": np.random.default_rng(0).standard_normal(1 << 20, np.float32),
+         "step": np.asarray(3, np.int32)}
+    with pytest.raises(ValueError, match="exceeds header region"):
+        BlobCheckpointer(c, psize=psize, header_pages=16).save(s, step=3)
+    header_pages = header_pages_for(s, psize)
+    assert header_pages > 16
+    ck = BlobCheckpointer(c, psize=psize, header_pages=header_pages)
+    assert ck.save(s, step=3).pages_total == 4097
+    got = ck.restore(jax.eval_shape(lambda: s))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(s)):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
 
 
 def test_incremental_save_shares_unchanged_pages(ckpt_env):

@@ -26,8 +26,7 @@ def _ratio(arch, step, B, T, overrides, remat="none"):
     cell = ShapeCell(f"probe_{step}", step, T, B)
     prog = build_cell(cfg, cell, mesh, strategy="tp", remat_policy=remat, accum=1)
     comp = prog.jitted().lower(*prog.abstract_args).compile()
-    from repro.launch.hlo import cost_analysis_dict
-    hlo = cost_analysis_dict(comp).get("flops", 0.0)
+    hlo = comp.cost_analysis().get("flops", 0.0)
     ana = cell_costs(cfg, cell, mesh, "tp", remat, 1).flops_per_device
     return ana / hlo
 

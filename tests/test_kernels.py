@@ -46,9 +46,77 @@ def test_ops_page_digest_dtypes(dtype, monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS", "interpret")
     x = jnp.asarray(RNG.standard_normal(5000), jnp.float32).astype(dtype)
     d_pal = ops.page_digest(x, page_bytes=4096)
-    monkeypatch.setenv("REPRO_PALLAS", "off")
+    monkeypatch.delenv("REPRO_PALLAS")
     d_ref = ops.page_digest(x, page_bytes=4096)
     np.testing.assert_array_equal(np.asarray(d_pal), np.asarray(d_ref))
+
+
+# A leaf sharded over a 1x4 mesh is digested under shard_map, each device
+# on its share of the pages.  Runs in a child with 4 virtual CPU devices so
+# this process keeps seeing one.
+_MESH_CHILD = """
+import json
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.kernels import ops
+from repro.kernels.hostdigest import host_page_digest
+from repro.launch.mesh import make_mesh
+
+PB = 2048
+mesh = make_mesh((1, 4), ("data", "model"))
+split = NamedSharding(mesh, P(None, "model"))
+one = jax.devices()[0]
+base = np.random.default_rng(0).standard_normal((4, 1200)) * 1000
+out = {}
+for name in ("float32", "bfloat16", "int32"):
+    x = jnp.asarray(base, jnp.float32).astype(name)
+    y = x.at[0, 7].set(12345).at[3, 1199].set(-777)
+    d_mesh = ops.page_digest(jax.device_put(x, split), PB)
+    d_one = ops.page_digest(jax.device_put(x, one), PB)
+    o_mesh = ops.page_digest(jax.device_put(y, split), PB)
+    o_one = ops.page_digest(jax.device_put(y, one), PB)
+    m_mesh = ops.delta_mask(d_mesh, o_mesh)
+    m_one = ops.delta_mask(d_one, o_one)
+    raw = np.asarray(x).reshape(-1).view(np.uint8)
+    host = [host_page_digest(raw[p * PB:(p + 1) * PB].tobytes(), PB)
+            for p in range(d_one.shape[0])]
+    out[name] = {
+        "n_pages": int(d_one.shape[0]),
+        "devices": [len(d_mesh.sharding.device_set), len(m_mesh.sharding.device_set)],
+        "digest_equal": bool(np.array_equal(np.asarray(d_mesh), np.asarray(d_one))),
+        "host_equal": [tuple(int(v) for v in r) for r in np.asarray(d_one)] == host,
+        "mask_mesh": np.asarray(m_mesh).tolist(),
+        "mask_one": np.asarray(m_one).tolist(),
+    }
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_digests():
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu", REPRO_PALLAS="interpret",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    run = subprocess.run([sys.executable, "-c", _MESH_CHILD], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=300)
+    assert run.returncode == 0, run.stderr[-3000:]
+    return json.loads(run.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+def test_ops_digest_on_mesh_matches_one_device(mesh_digests, dtype):
+    got = mesh_digests[dtype]
+    assert got["n_pages"] % 4 != 0  # the pages are padded to the mesh size
+    assert got["devices"] == [4, 4]  # the shard_map path ran
+    assert got["digest_equal"] and got["host_equal"]
+    assert got["mask_mesh"] == got["mask_one"]
+    assert got["mask_one"][0] and got["mask_one"][-1] and sum(got["mask_one"]) == 2
 
 
 # ---------------------------------------------------------------- delta mask
