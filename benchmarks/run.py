@@ -12,9 +12,8 @@ import sys
 
 from benchmarks.common import Reporter
 
-BENCHES = ["append", "read", "meta", "space", "gc", "cache", "ckpt",
-           "failover", "durability", "watch", "ring", "kernels",
-           "roofline", "concurrency", "e2e"]
+BENCHES = ["append", "read", "meta", "space", "gc", "cache",
+           "failover", "durability", "watch", "ring", "concurrency", "e2e"]
 
 
 def main() -> None:
@@ -34,8 +33,6 @@ def main() -> None:
             from benchmarks import bench_gc as m
         elif name == "cache":
             from benchmarks import bench_cache as m
-        elif name == "ckpt":
-            from benchmarks import bench_ckpt as m
         elif name == "failover":
             from benchmarks import bench_failover as m
         elif name == "durability":
@@ -44,10 +41,6 @@ def main() -> None:
             from benchmarks import bench_watch as m
         elif name == "ring":
             from benchmarks import bench_ring as m
-        elif name == "kernels":
-            from benchmarks import bench_kernels as m
-        elif name == "roofline":
-            from benchmarks import bench_roofline as m
         elif name == "concurrency":
             from benchmarks import bench_concurrency as m
         elif name == "e2e":

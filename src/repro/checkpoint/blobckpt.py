@@ -54,6 +54,7 @@ import jax
 from repro.core.blob import BlobClient
 from repro.core.version_manager import RetiredVersion, VersionUnpublished
 from repro.kernels import ops as kops
+from repro.spans import span
 
 
 @dataclass
@@ -64,6 +65,7 @@ class CheckpointStats:
     written_bytes: int
     pages_total: int
     pages_written: int
+    d2h_bytes: int = 0   # bytes pulled from the device (the dirty leaves)
 
     @property
     def sharing_fraction(self) -> float:
@@ -129,7 +131,17 @@ class BlobCheckpointer:
 
     # ------------------------------------------------------------------- save
     def save(self, state, step: int, extra: Optional[Dict] = None) -> CheckpointStats:
-        """Write an incremental checkpoint; returns sharing stats."""
+        """Write an incremental checkpoint; returns sharing stats.
+
+        Its phases are spans (``repro.spans``): ``ckpt.save`` around it
+        all; per leaf ``ckpt.digest``, and for a dirty leaf ``ckpt.d2h``
+        and ``ckpt.pack``; the ``blob.*`` spans of ``write_many``; then
+        ``ckpt.commit``.
+        """
+        with span("ckpt.save"):
+            return self._save(state, step, extra)
+
+    def _save(self, state, step: int, extra: Optional[Dict]) -> CheckpointStats:
         leaves = flatten_with_paths(state)
         psz = self.psize
 
@@ -153,6 +165,7 @@ class BlobCheckpointer:
 
         written_bytes = 0
         pages_written = 0
+        d2h_bytes = 0
         pages_total = (total - self.header_bytes) // psz
         manifest_leaves = []
         new_digests: Dict[str, np.ndarray] = {}
@@ -168,14 +181,15 @@ class BlobCheckpointer:
         dirty_digests: List[List[Tuple[int, int]]] = []
         for path, leaf in leaves:
             off, nbytes = layout[path]
-            # digest where the leaf lives (its device, or its mesh)
-            dg_dev = kops.page_digest(leaf, page_bytes=psz)
-            old = self._digests.get(path)
-            if layout_changed or old is None or old.shape != dg_dev.shape:
-                dirty = np.ones(dg_dev.shape[0], dtype=bool)
-            else:
-                dirty = np.asarray(kops.delta_mask(dg_dev, old))
-            dg = np.asarray(dg_dev)
+            with span("ckpt.digest"):
+                # digest where the leaf lives (its device, or its mesh)
+                dg_dev = kops.page_digest(leaf, page_bytes=psz)
+                old = self._digests.get(path)
+                if layout_changed or old is None or old.shape != dg_dev.shape:
+                    dirty = np.ones(dg_dev.shape[0], dtype=bool)
+                else:
+                    dirty = np.asarray(kops.delta_mask(dg_dev, old))
+                dg = np.asarray(dg_dev)
             new_digests[path] = dg
             manifest_leaves.append({
                 "path": path,
@@ -186,81 +200,85 @@ class BlobCheckpointer:
             })
             if not dirty.any():
                 continue  # clean leaf: nothing leaves the device
-            raw = np.ascontiguousarray(jax.device_get(leaf)).reshape(-1).view(np.uint8)
-            # write contiguous dirty page runs, zero-padded to full pages:
-            # page-aligned writes are BlobSeer's fast path (no boundary
-            # merging) and keep blob growth contiguous
-            n_pages = dg.shape[0]
-            i = 0
-            while i < n_pages:
-                if not dirty[i]:
-                    i += 1
-                    continue
-                j = i
-                while j < n_pages and dirty[j]:
-                    j += 1
-                lo = i * psz
-                chunk = raw[lo : j * psz].tobytes()
-                pad = (j - i) * psz - len(chunk)
-                if pad:
-                    chunk = chunk + b"\0" * pad
-                dirty_writes.append((chunk, off + lo))
-                dirty_digests.append(
-                    [(int(dg[k, 0]), int(dg[k, 1])) for k in range(i, j)])
-                written_bytes += len(chunk)
-                pages_written += j - i
-                i = j
+            with span("ckpt.d2h"):
+                raw = np.ascontiguousarray(jax.device_get(leaf)).reshape(-1).view(np.uint8)
+            d2h_bytes += _nbytes(leaf)
+            with span("ckpt.pack"):
+                # write contiguous dirty page runs, zero-padded to full pages:
+                # page-aligned writes are BlobSeer's fast path (no boundary
+                # merging) and keep blob growth contiguous
+                n_pages = dg.shape[0]
+                i = 0
+                while i < n_pages:
+                    if not dirty[i]:
+                        i += 1
+                        continue
+                    j = i
+                    while j < n_pages and dirty[j]:
+                        j += 1
+                    lo = i * psz
+                    chunk = raw[lo : j * psz].tobytes()
+                    pad = (j - i) * psz - len(chunk)
+                    if pad:
+                        chunk = chunk + b"\0" * pad
+                    dirty_writes.append((chunk, off + lo))
+                    dirty_digests.append(
+                        [(int(dg[k, 0]), int(dg[k, 1])) for k in range(i, j)])
+                    written_bytes += len(chunk)
+                    pages_written += j - i
+                    i = j
 
         if dirty_writes:
             self.client.write_many(self.blob_id, dirty_writes,
                                    digests=dirty_digests)
 
-        manifest = {
-            "format": 1,
-            "step": step,
-            "total_bytes": total,
-            "leaves": manifest_leaves,
-            "extra": extra or {},
-            "digests": {p: d.tobytes().hex() for p, d in new_digests.items()},
-        }
-        payload = zlib.compress(json.dumps(manifest).encode())
-        record = len(payload).to_bytes(8, "little") + payload
-        if len(record) > self.header_bytes - self.manifest_off:
-            raise ValueError(
-                f"manifest ({len(record)}B) exceeds header region "
-                f"({self.header_bytes - self.manifest_off}B); raise header_pages"
-            )
-        # commit protocol: manifest, then the commit pointer naming the
-        # manifest write's snapshot version (restores read AT that version)
-        vm_version = self.client.write(self.blob_id, record, self.manifest_off)
-        self.client.sync(self.blob_id, vm_version)
-        # roll the GC pin forward NOW, while the manifest snapshot is
-        # still the newest published version (always kept): pinning only
-        # after the commit write would leave a window where a retention
-        # GC round retires the manifest of the just-committed checkpoint
-        lease = self.client.pin(self.blob_id, vm_version)
-        try:
-            commit = vm_version.to_bytes(8, "little") + b"\1"
-            vc = self.client.write(self.blob_id, commit, 0)
-            self.client.sync(self.blob_id, vc)
-        except BaseException:
-            # failed commit: release the just-taken pin or it leaks an
-            # untimed lease that excludes this snapshot from GC forever
+        with span("ckpt.commit"):
+            manifest = {
+                "format": 1,
+                "step": step,
+                "total_bytes": total,
+                "leaves": manifest_leaves,
+                "extra": extra or {},
+                "digests": {p: d.tobytes().hex() for p, d in new_digests.items()},
+            }
+            payload = zlib.compress(json.dumps(manifest).encode())
+            record = len(payload).to_bytes(8, "little") + payload
+            if len(record) > self.header_bytes - self.manifest_off:
+                raise ValueError(
+                    f"manifest ({len(record)}B) exceeds header region "
+                    f"({self.header_bytes - self.manifest_off}B); raise header_pages"
+                )
+            # commit protocol: manifest, then the commit pointer naming the
+            # manifest write's snapshot version (restores read AT that version)
+            vm_version = self.client.write(self.blob_id, record, self.manifest_off)
+            self.client.sync(self.blob_id, vm_version)
+            # roll the GC pin forward NOW, while the manifest snapshot is
+            # still the newest published version (always kept): pinning only
+            # after the commit write would leave a window where a retention
+            # GC round retires the manifest of the just-committed checkpoint
+            lease = self.client.pin(self.blob_id, vm_version)
             try:
-                self.client.unpin(lease)
-            except Exception:
-                pass  # best effort (e.g. wire down); save() still fails
-            raise
-        if self._manifest_lease is not None:
-            self.client.unpin(self._manifest_lease)
-        self._manifest_lease = lease
+                commit = vm_version.to_bytes(8, "little") + b"\1"
+                vc = self.client.write(self.blob_id, commit, 0)
+                self.client.sync(self.blob_id, vc)
+            except BaseException:
+                # failed commit: release the just-taken pin or it leaks an
+                # untimed lease that excludes this snapshot from GC forever
+                try:
+                    self.client.unpin(lease)
+                except Exception:
+                    pass  # best effort (e.g. wire down); save() still fails
+                raise
+            if self._manifest_lease is not None:
+                self.client.unpin(self._manifest_lease)
+            self._manifest_lease = lease
         self._digests = new_digests
         self._layout = layout
         written_bytes += len(record) + len(commit)
         return CheckpointStats(
             version=vc, step=step, total_bytes=total,
             written_bytes=written_bytes, pages_total=pages_total,
-            pages_written=pages_written,
+            pages_written=pages_written, d2h_bytes=d2h_bytes,
         )
 
     # ---------------------------------------------------------------- restore

@@ -48,6 +48,7 @@ from repro.core.version_manager import (
     owner_fn_for_lineage,
 )
 from repro.kernels.hostdigest import host_page_digest
+from repro.spans import span
 
 # Backwards-compatible alias: the node cache grew up and moved to
 # repro.core.cache (shared with the page cache and the accounting
@@ -523,30 +524,32 @@ class BlobClient:
         # -- phase 1: optimistic pre-store of every fully covered page --
         # Appends presume a page-aligned burst base (cumulative offsets
         # from 0); writes know their offsets exactly.
-        cursor = 0
-        plans: List[Tuple[int, List[Tuple[int, bytes]]]] = []
-        for idx, (buf, off) in enumerate(items):
-            p_off = cursor if is_append else off
-            if is_append:
-                cursor += len(buf)
-            p0_pre, _ = pages_spanned(p_off, len(buf), psize)
-            plans.append((idx, self._plan_full_pages(buf, p_off, psize, p0_pre)))
-        barrier = self._store_planned(
-            plans, stored, psize=psize, digests=digests,
-            use_dedup=use_dedup, acquired=acquired, blob_id=blob_id)
-        pd_wire = [
-            tuple((pid, rel, provs, ln)
-                  for rel, (pid, provs, ln) in sorted(s.items()))
-            for s in stored
-        ]
+        with span("blob.store_pages"):
+            cursor = 0
+            plans: List[Tuple[int, List[Tuple[int, bytes]]]] = []
+            for idx, (buf, off) in enumerate(items):
+                p_off = cursor if is_append else off
+                if is_append:
+                    cursor += len(buf)
+                p0_pre, _ = pages_spanned(p_off, len(buf), psize)
+                plans.append((idx, self._plan_full_pages(buf, p_off, psize, p0_pre)))
+            barrier = self._store_planned(
+                plans, stored, psize=psize, digests=digests,
+                use_dedup=use_dedup, acquired=acquired, blob_id=blob_id)
+            pd_wire = [
+                tuple((pid, rel, provs, ln)
+                      for rel, (pid, provs, ln) in sorted(s.items()))
+                for s in stored
+            ]
 
         # -- phase 2: ONE batched version assignment for the burst --
-        infos = self.vm.assign_versions_many(
-            [(blob_id, None if is_append else off, len(buf), pd_wire[idx])
-             for idx, (buf, off) in enumerate(items)],
-            client=self.name,
-            keys=[self._assign_key() for _ in items],
-        )
+        with span("blob.publish"):
+            infos = self.vm.assign_versions_many(
+                [(blob_id, None if is_append else off, len(buf), pd_wire[idx])
+                 for idx, (buf, off) in enumerate(items)],
+                client=self.name,
+                keys=[self._assign_key() for _ in items],
+            )
 
         if is_append and infos[0].offset % psize != 0:
             # Phase-2 re-stripe: the burst's presumed page-aligned base
@@ -558,72 +561,74 @@ class BlobClient:
             # the re-striped pages carry new content phases, so any
             # caller-supplied digests no longer apply (the host twin
             # re-fingerprints).
-            if use_dedup and acquired:
-                self.dedup_index.unreference(acquired, peer=self.name)
-                acquired = []
-            plans = []
+            with span("blob.store_pages"):
+                if use_dedup and acquired:
+                    self.dedup_index.unreference(acquired, peer=self.name)
+                    acquired = []
+                plans = []
+                for idx, (buf, _off) in enumerate(items):
+                    stored[idx].clear()
+                    plans.append((idx, self._plan_full_pages(
+                        buf, infos[idx].offset, psize, infos[idx].p0)))
+                barrier = max(barrier, self._store_planned(
+                    plans, stored, psize=psize, use_dedup=use_dedup,
+                    acquired=acquired, blob_id=blob_id))
+
+        with span("blob.publish"):
+            # -- phase 3: boundary pages, intra-batch merges resolved locally --
+            prebatch_size = infos[0].prev_size
+            prebatch_version = infos[0].version - 1
+
+            def make_old_read(idx: int) -> Callable[[int, int], bytes]:
+                def old_read(a: int, b: int) -> bytes:
+                    # Content of snapshot v_{idx}-1 over [a, b): pre-batch
+                    # bytes below the batch's starting size (the only remote
+                    # part — and the only wait, on the pre-batch writer),
+                    # overlaid with every earlier buffer in the batch (their
+                    # versions are exactly the snapshots between the batch
+                    # base and v_idx).
+                    out = bytearray(b - a)
+                    lo_remote = min(b, prebatch_size)
+                    if a < lo_remote and prebatch_version > 0:
+                        self.vm.wait_metadata(blob_id, prebatch_version)
+                        out[0:lo_remote - a] = self._read_unpublished(
+                            blob_id, prebatch_version, a, lo_remote - a,
+                            infos[idx])
+                    for j in range(idx):
+                        jbuf = items[j][0]
+                        joff = infos[j].offset
+                        lo, hi = max(a, joff), min(b, joff + len(jbuf))
+                        if hi > lo:
+                            out[lo - a:hi - a] = jbuf[lo - joff:hi - joff]
+                    return bytes(out)
+                return old_read
+
+            versions: List[int] = []
             for idx, (buf, _off) in enumerate(items):
-                stored[idx].clear()
-                plans.append((idx, self._plan_full_pages(
-                    buf, infos[idx].offset, psize, infos[idx].p0)))
-            barrier = max(barrier, self._store_planned(
-                plans, stored, psize=psize, use_dedup=use_dedup,
-                acquired=acquired, blob_id=blob_id))
+                info = infos[idx]
+                stored_boundary, b3 = self._store_boundary_pages(
+                    blob_id, buf, info.offset, len(buf), psize, info,
+                    stored[idx], old_read=make_old_read(idx),
+                )
+                barrier = max(barrier, b3)
+                pd_final = tuple(
+                    (pid, rel, provs, ln)
+                    for rel, (pid, provs, ln) in sorted(stored[idx].items())
+                )
+                if stored_boundary or pd_final != pd_wire[idx]:
+                    self.vm.register_pd(blob_id, info.version, pd_final,
+                                        client=self.name)
 
-        # -- phase 3: boundary pages, intra-batch merges resolved locally --
-        prebatch_size = infos[0].prev_size
-        prebatch_version = infos[0].version - 1
+                # -- phase 4a: weave each update's metadata (border ranges of
+                # concurrent batch members resolve locally from AssignInfo) --
+                self._build_and_complete(blob_id, info, pd_final, complete=False)
+                versions.append(info.version)
 
-        def make_old_read(idx: int) -> Callable[[int, int], bytes]:
-            def old_read(a: int, b: int) -> bytes:
-                # Content of snapshot v_{idx}-1 over [a, b): pre-batch
-                # bytes below the batch's starting size (the only remote
-                # part — and the only wait, on the pre-batch writer),
-                # overlaid with every earlier buffer in the batch (their
-                # versions are exactly the snapshots between the batch
-                # base and v_idx).
-                out = bytearray(b - a)
-                lo_remote = min(b, prebatch_size)
-                if a < lo_remote and prebatch_version > 0:
-                    self.vm.wait_metadata(blob_id, prebatch_version)
-                    out[0:lo_remote - a] = self._read_unpublished(
-                        blob_id, prebatch_version, a, lo_remote - a,
-                        infos[idx])
-                for j in range(idx):
-                    jbuf = items[j][0]
-                    joff = infos[j].offset
-                    lo, hi = max(a, joff), min(b, joff + len(jbuf))
-                    if hi > lo:
-                        out[lo - a:hi - a] = jbuf[lo - joff:hi - joff]
-                return bytes(out)
-            return old_read
-
-        versions: List[int] = []
-        for idx, (buf, _off) in enumerate(items):
-            info = infos[idx]
-            stored_boundary, b3 = self._store_boundary_pages(
-                blob_id, buf, info.offset, len(buf), psize, info,
-                stored[idx], old_read=make_old_read(idx),
-            )
-            barrier = max(barrier, b3)
-            pd_final = tuple(
-                (pid, rel, provs, ln)
-                for rel, (pid, provs, ln) in sorted(stored[idx].items())
-            )
-            if stored_boundary or pd_final != pd_wire[idx]:
-                self.vm.register_pd(blob_id, info.version, pd_final,
-                                    client=self.name)
-
-            # -- phase 4a: weave each update's metadata (border ranges of
-            # concurrent batch members resolve locally from AssignInfo) --
-            self._build_and_complete(blob_id, info, pd_final, complete=False)
-            versions.append(info.version)
-
-        # -- phase 4b: store barrier, then ONE batched completion --
-        self._await(barrier)
-        self.vm.metadata_complete_many(
-            [(blob_id, v) for v in versions], client=self.name)
-        return versions
+            # -- phase 4b: store barrier, then ONE batched completion --
+            self._await(barrier)
+            self.vm.metadata_complete_many(
+                [(blob_id, v) for v in versions], client=self.name)
+            return versions
 
     # ------------------------------------------------------- update internals
     def _plan_full_pages(

@@ -69,6 +69,7 @@ from repro.core.pages import UpdateExtent, iter_created_nodes, node_children
 from repro.core.placement import logical_pid
 from repro.core.transport import EndpointDown
 from repro.core.version_manager import VersionUnpublished, owner_fn_for_lineage
+from repro.spans import span
 
 
 def mark_live(
@@ -330,45 +331,51 @@ def collect_garbage(
     Every mark/sweep operation crosses the wire — zero direct shard or
     provider-store mutations — and the whole round is deterministic
     under the simulated clock.  Returns round statistics.
+
+    Spans (``repro.spans``): ``gc.round`` around it all, ``gc.mark`` and
+    ``gc.sweep`` (the orphan pass included) inside it.
     """
-    keep = keep or {}
-    vm = svc.vm
-    retired_now = 0
-    kept_total = 0
-    for blob_id in vm.known_blobs():
-        kept_v, newly = vm.plan_retirement(
-            blob_id,
-            keep_extra=keep.get(blob_id),
-            explicit=blob_id in keep,
-            client=client,
-        )
-        kept_total += len(kept_v)
-        retired_now += len(newly)
-        if newly:
-            vm.wait_reads_drained(blob_id, newly)
+    with span("gc.round"):
+        keep = keep or {}
+        vm = svc.vm
+        retired_now = 0
+        kept_total = 0
+        for blob_id in vm.known_blobs():
+            kept_v, newly = vm.plan_retirement(
+                blob_id,
+                keep_extra=keep.get(blob_id),
+                explicit=blob_id in keep,
+                client=client,
+            )
+            kept_total += len(kept_v)
+            retired_now += len(newly)
+            if newly:
+                vm.wait_reads_drained(blob_id, newly)
 
-    pending = {
-        blob_id: recs
-        for blob_id in vm.known_blobs()
-        if (recs := vm.sweep_pending(blob_id))
-    }
+        pending = {
+            blob_id: recs
+            for blob_id in vm.known_blobs()
+            if (recs := vm.sweep_pending(blob_id))
+        }
 
-    live_nodes, live_pages, mark_rounds, mark_keys = mark_live(svc, peer=client)
-    stats = _sweep(svc, pending, live_nodes, live_pages, peer=client)
-    if orphan_grace is not None:
-        stats.update(collect_orphans(svc, orphan_grace, peer=client))
-    else:
-        stats.update({"orphan_pages": 0, "orphan_bytes": 0})
-    stats.update({
-        "live_nodes": len(live_nodes),
-        "live_pages": len(live_pages),
-        "kept_versions": kept_total,
-        "retired_versions": retired_now,
-        "mark_rounds": mark_rounds,
-        "mark_keys": mark_keys,
-        "sweep_versions": sum(len(r) for r in pending.values()),
-    })
-    return stats
+        with span("gc.mark"):
+            live_nodes, live_pages, mark_rounds, mark_keys = mark_live(svc, peer=client)
+        with span("gc.sweep"):
+            stats = _sweep(svc, pending, live_nodes, live_pages, peer=client)
+            if orphan_grace is not None:
+                stats.update(collect_orphans(svc, orphan_grace, peer=client))
+            else:
+                stats.update({"orphan_pages": 0, "orphan_bytes": 0})
+        stats.update({
+            "live_nodes": len(live_nodes),
+            "live_pages": len(live_pages),
+            "kept_versions": kept_total,
+            "retired_versions": retired_now,
+            "mark_rounds": mark_rounds,
+            "mark_keys": mark_keys,
+            "sweep_versions": sum(len(r) for r in pending.values()),
+        })
+        return stats
 
 
 def resweep_after_restore(svc, client: str = "gc-restore") -> Dict[str, int]:
