@@ -45,7 +45,7 @@ from __future__ import annotations
 import json
 import zlib
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -66,6 +66,7 @@ class CheckpointStats:
     pages_total: int
     pages_written: int
     d2h_bytes: int = 0   # bytes pulled from the device (the dirty leaves)
+    pack_copy_bytes: int = 0  # bytes of runs copied to be zero-padded
 
     @property
     def sharing_fraction(self) -> float:
@@ -166,6 +167,7 @@ class BlobCheckpointer:
         written_bytes = 0
         pages_written = 0
         d2h_bytes = 0
+        pack_copy_bytes = 0
         pages_total = (total - self.header_bytes) // psz
         manifest_leaves = []
         new_digests: Dict[str, np.ndarray] = {}
@@ -174,7 +176,7 @@ class BlobCheckpointer:
         # one write() per run), but the whole save pays a single
         # version-manager assignment round trip and a single batched
         # completion — the scale-out write plane under the checkpointer
-        dirty_writes: List[Tuple[bytes, int]] = []
+        dirty_writes: List[Tuple[Union[bytes, memoryview], int]] = []
         # per run, the delta scan's page fingerprints ride along into
         # write_many as the dedup-handshake input — the content-hash
         # index matches on exactly these digests, nothing hashes twice
@@ -204,10 +206,14 @@ class BlobCheckpointer:
                 raw = np.ascontiguousarray(jax.device_get(leaf)).reshape(-1).view(np.uint8)
             d2h_bytes += _nbytes(leaf)
             with span("ckpt.pack"):
-                # write contiguous dirty page runs, zero-padded to full pages:
-                # page-aligned writes are BlobSeer's fast path (no boundary
-                # merging) and keep blob growth contiguous
+                # write contiguous dirty page runs of full pages: page-aligned
+                # writes are BlobSeer's fast path (no boundary merging) and
+                # keep blob growth contiguous.  A run goes out as a read-only
+                # view of the D2H buffer (the store copies each page out of
+                # it once); only a run whose last page passes the leaf's end
+                # is copied here, to be zero-padded
                 n_pages = dg.shape[0]
+                view = memoryview(raw).toreadonly()
                 i = 0
                 while i < n_pages:
                     if not dirty[i]:
@@ -216,11 +222,12 @@ class BlobCheckpointer:
                     j = i
                     while j < n_pages and dirty[j]:
                         j += 1
-                    lo = i * psz
-                    chunk = raw[lo : j * psz].tobytes()
-                    pad = (j - i) * psz - len(chunk)
-                    if pad:
-                        chunk = chunk + b"\0" * pad
+                    lo, hi = i * psz, j * psz
+                    if hi <= raw.size:
+                        chunk = view[lo:hi]
+                    else:
+                        chunk = raw[lo:].tobytes() + b"\0" * (hi - raw.size)
+                        pack_copy_bytes += hi - lo
                     dirty_writes.append((chunk, off + lo))
                     dirty_digests.append(
                         [(int(dg[k, 0]), int(dg[k, 1])) for k in range(i, j)])
@@ -279,6 +286,7 @@ class BlobCheckpointer:
             version=vc, step=step, total_bytes=total,
             written_bytes=written_bytes, pages_total=pages_total,
             pages_written=pages_written, d2h_bytes=d2h_bytes,
+            pack_copy_bytes=pack_copy_bytes,
         )
 
     # ---------------------------------------------------------------- restore

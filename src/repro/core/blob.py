@@ -463,7 +463,8 @@ class BlobClient:
         ever wait on a pre-burst writer.  Returns the assigned versions
         in buffer order.
 
-        ``dedup``/``digests``: see :meth:`write_many`.
+        ``dedup``/``digests`` and the buffers' types: see
+        :meth:`write_many`.
         """
         return self._update_many(blob_id, [(buf, None) for buf in bufs],
                                  digests=digests, dedup=dedup)
@@ -481,6 +482,12 @@ class BlobClient:
         layer uses this for its dirty-page runs).  Offsets are
         validated against the batch's own running size — item *k* may
         extend the blob and item *k+1* may write into the extension.
+
+        A ``buf`` may be ``bytes`` or any contiguous 1-D byte buffer (a
+        ``bytearray``, a ``memoryview`` of format ``B``).  Every stored
+        page is an independent ``bytes`` copy (a slice of a ``bytes``
+        buffer, copied once out of any other), so the caller may reuse
+        or change the buffer once the call returns.
 
         ``dedup`` (default: the client's ``dedup`` constructor flag)
         enables the two-phase dedup handshake on the burst's full
@@ -636,11 +643,14 @@ class BlobClient:
     ) -> List[Tuple[int, bytes]]:
         """``(rel_page, payload)`` for every page fully covered by the
         byte range ``[off, off+len(buf))`` (boundary pages are phase 3's
-        job).  ``p0`` is the update's first touched page."""
+        job).  ``p0`` is the update's first touched page.  A payload is a
+        slice of a ``bytes`` buffer (``bytes()`` of it is the slice
+        itself), else one ``bytes`` copy out of the buffer: stored pages
+        never alias the caller's memory."""
         full_lo = -(-off // psize)                 # first fully covered page
         full_hi = (off + len(buf)) // psize        # one past last fully covered
         return [
-            (k - p0, buf[k * psize - off:(k + 1) * psize - off])
+            (k - p0, bytes(buf[k * psize - off:(k + 1) * psize - off]))
             for k in range(full_lo, full_hi)
         ]
 

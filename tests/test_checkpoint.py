@@ -69,6 +69,34 @@ def test_incremental_save_shares_unchanged_pages(ckpt_env):
     assert st2.sharing_fraction > 0.5
 
 
+def test_pack_copies_only_the_padded_run(ckpt_env):
+    """Runs of whole pages go to the store as views of the D2H buffer;
+    only a run whose last page passes its leaf's end is copied to be
+    padded, and every save restores bit for bit."""
+    svc, c = ckpt_env
+    ck = BlobCheckpointer(c, psize=256, header_pages=8)
+    k0, k1 = jax.random.split(jax.random.PRNGKey(7))
+    state = {"whole": jax.random.normal(k0, (256,)),     # 4 pages exactly
+             "tail": jax.random.normal(k1, (70,))}       # 280 B: 2 pages
+    like = jax.eval_shape(lambda: state)
+
+    def save_and_check(step, pages, copied):
+        stats = ck.save(state, step=step)
+        assert (stats.pages_written, stats.pack_copy_bytes) == (pages, copied)
+        got = ck.restore(like, version=stats.version)
+        for key, leaf in state.items():
+            assert got[key].tobytes() == np.asarray(leaf).tobytes()
+
+    save_and_check(0, 6, 2 * 256)       # all dirty: the tail's one run
+    state["whole"] = state["whole"].at[130].set(-1.0)
+    save_and_check(1, 1, 0)             # tail clean, whole-page run a view
+    state["tail"] = state["tail"].at[3].set(-1.0)
+    save_and_check(2, 1, 0)             # the tail's first page lies inside
+    state["tail"] = state["tail"].at[69].set(-1.0)
+    save_and_check(3, 1, 256)           # its last page is padded
+    assert ck.save(state, step=4).pack_copy_bytes == 0
+
+
 def test_old_checkpoints_remain_readable(ckpt_env):
     svc, c = ckpt_env
     ck = BlobCheckpointer(c, psize=256, header_pages=8)

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import threading
 
+import numpy as np
 import pytest
 
 from repro.core import BlobSeerService
@@ -220,6 +221,55 @@ def test_write_many_boundary_merge_intra_batch():
     assert got == bytes(ref)
     # every intermediate snapshot is independently readable (weave ok)
     assert c.read(bid, 2, 0, 64) == b"x" * 5 + b"y" * 10 + b"x" * 49
+
+
+_VIEW_BUFS = [bytes(range(40)), b"b" * 7, bytes(range(100, 133)), b"d" * 48]
+# item 0 unaligned over the base; item 1 inside item 0 (an intra-batch
+# overlap merged from the batch's own buffer); item 2 extends the blob
+# from an unaligned offset; item 3 rewrites whole pages of items 0 and 1
+_VIEW_OFFSETS = [5, 30, 64, 16]
+
+
+def _batch_through(verb: str, as_views: bool):
+    svc = BlobSeerService(n_providers=4, n_meta_shards=2)
+    c = svc.client()
+    bid = c.create(psize=16)
+    c.write(bid, b"x" * 70, 0)              # unaligned size: appends re-stripe
+    sources = [np.frombuffer(b, np.uint8).copy() for b in _VIEW_BUFS]
+    if as_views:
+        # read-only numpy-backed views, the last one left writable
+        bufs = [memoryview(a).toreadonly() for a in sources[:-1]]
+        bufs.append(memoryview(sources[-1]))
+    else:
+        bufs = list(_VIEW_BUFS)
+    if verb == "write_many":
+        vs = c.write_many(bid, list(zip(bufs, _VIEW_OFFSETS)))
+    else:
+        vs = c.append_many(bid, bufs)
+    for a in sources:                        # the caller reuses its buffers
+        a.fill(0xEE)
+    snapshots = [c.read(bid, v, 0, c.get_size(bid, v)) for v in vs]
+    pages = [p.store.get(pid) for p in svc.pm.all_providers()
+             for pid in p.store.iter_pids()]
+    return vs, snapshots, pages
+
+
+@pytest.mark.parametrize("verb", ["write_many", "append_many"])
+def test_batched_verbs_take_byte_views(verb):
+    """A memoryview item stores and reads back exactly what the same
+    bytes do, and every stored page is its own ``bytes`` copy."""
+    vs_b, snaps_b, _ = _batch_through(verb, as_views=False)
+    vs_v, snaps_v, pages = _batch_through(verb, as_views=True)
+    assert vs_v == vs_b == [2, 3, 4, 5]
+    assert snaps_v == snaps_b
+    assert pages and all(type(p) is bytes for p in pages)
+    if verb == "append_many":
+        assert snaps_v[-1] == b"x" * 70 + b"".join(_VIEW_BUFS)
+    else:
+        ref = bytearray(b"x" * 70 + b"\0" * 27)
+        for b, off in zip(_VIEW_BUFS, _VIEW_OFFSETS):
+            ref[off:off + len(b)] = b
+        assert snaps_v[-1] == bytes(ref)
 
 
 def test_mixed_append_write_batch_rejected():
