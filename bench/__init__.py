@@ -4,5 +4,6 @@
 runs one cell of ``BENCHMARK.json`` on the accelerator it finds and prints
 one JSON result line.  Configurations (``configs/``), traffic mixes
 (``traffic/``) and per-layer metric readers (``metrics/``) are files of
-their own, found by the names in ``BENCHMARK.json``.
+their own, found by the names in ``BENCHMARK.json``; a configuration's
+architecture is a module of ``families/``, found by its ``model_type``.
 """

@@ -16,10 +16,19 @@ the file gives no limit is read but not compared.
   read back at its version before the next steps change the state: a
   seed-drawn page of every leaf against the same bytes of the state on the
   device, and its manifest's step and reader cursor (one each);
-* ``readback_bytes_differ``: bytes of the newest checkpoint, read back whole
-  at its version after the window's last GC round, that differ from the
-  state on the device (and its step and reader cursor as the manifest
-  holds them);
+* ``readback_bytes_differ``: bytes of the run's newest checkpoint, read
+  back whole at its version after the window, that differ from the state
+  on the device (and its step and reader cursor as the manifest holds
+  them); in a cell that resumes, one more resume runs outside the window,
+  and its restore is the read-back and its state, before its first step,
+  the state compared;
+* ``resume_bytes_differ``: bytes of that resumed state on the device that
+  differ from the newest checkpoint read back at its version leaf by leaf,
+  apart from the restore; plus one for each of the restored step and the
+  rebuilt reader's cursor that differs from what was saved;
+* ``resume_loss_gap``: by the worst resume of the run (set-up's, the
+  window's and the check's), |loss of its first step − the loss the same
+  step gave without the interruption| / the latter;
 * ``digest_pages_differ``: of a seed-drawn sample of pages, the manifest's
   digests (the program's kernels) that differ from the reference digest of
   the bytes read back.
@@ -63,15 +72,17 @@ def _raw(x) -> np.ndarray:
     return np.ascontiguousarray(np.asarray(x)).reshape(-1).view(np.uint8)
 
 
-def readback(job, seed: int, page_bytes: int) -> Tuple[int, int]:
+def readback(job, seed: int, page_bytes: int, read=None) -> Tuple[int, int]:
     """(bytes differing, sampled pages whose digest differs) of the newest
-    checkpoint against the state on the device."""
+    checkpoint against the state on the device.  ``read`` is that
+    checkpoint as a resume has just restored it, (state, manifest); without
+    it the checkpoint is restored here, at the newest save's version."""
     s = job.sys
-    last = job.saves[-1]
-    restored, manifest = s.ckpt.restore(s.abstract, version=last.version,
-                                        with_manifest=True)
+    last, reader = job.newest
+    restored, manifest = read or s.ckpt.restore(s.abstract, version=last.version,
+                                                with_manifest=True)
     differ = 0
-    if manifest["step"] != last.step or manifest["extra"].get("reader") != job.save_readers[-1]:
+    if manifest["step"] != last.step or manifest["extra"].get("reader") != reader:
         differ += 1
     rows = []   # (path, restored bytes) for the digest sample
     for (path, got), (_, want) in zip(gen.leaves_with_paths(restored),
@@ -96,7 +107,7 @@ def save_sample(job, seed: int) -> int:
     has not moved since; plus one for each of the manifest's step and
     reader cursor that differs."""
     s = job.sys
-    last, reader = job.saves[-1], job.save_readers[-1]
+    last, reader = job.newest
     manifest, at = s.ckpt.read_manifest(last.version)
     differ = int(manifest["step"] != last.step) + int(manifest["extra"].get("reader") != reader)
     psize = s.ckpt.psize
@@ -115,6 +126,28 @@ def save_sample(job, seed: int) -> int:
                             np.uint8)
         want = _raw(_elements(leaf, start // item, size // item))
         differ += int(np.count_nonzero(got != want)) if got.size == want.size else size
+    return differ
+
+
+def resumed_state(job, manifest: dict) -> int:
+    """Bytes of the state on the device, just resumed from ``manifest``, that
+    differ from the newest checkpoint read back at its version one leaf at a
+    time (so the host holds one leaf, not a state); plus one for each of the
+    restored step and the rebuilt reader's cursor that differs from what was
+    saved."""
+    s = job.sys
+    last, reader = job.newest
+    differ = int(manifest["step"] != last.step) + int(s.reader.state_dict() != reader)
+    saved, at = s.ckpt.read_manifest(last.version)
+    recs = {rec["path"]: rec for rec in saved["leaves"]}
+    for path, leaf in gen.leaves_with_paths(s.state):
+        nbytes = leaf.size * leaf.dtype.itemsize
+        rec = recs.get(path)
+        if rec is None or rec["nbytes"] != nbytes:
+            differ += nbytes
+            continue
+        got = np.frombuffer(s.client.read(s.ckpt.blob_id, at, rec["offset"], nbytes), np.uint8)
+        differ += int(np.count_nonzero(got != _raw(jax.device_get(leaf))))
     return differ
 
 

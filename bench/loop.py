@@ -13,7 +13,15 @@ Operations:
   compares (each loss, the first gradient as Adam's state holds it, the
   change of the fp32 master weights after ``n`` steps);
 * ``save``: ``BlobCheckpointer.save`` of the device state;
-* ``gc``: one garbage-collection round over the deployment.
+* ``gc``: one garbage-collection round over the deployment;
+* ``resume``: what ``repro.launch.train.main`` does after a kill: the
+  device state dropped and freed, a fresh client and checkpointer on the
+  same lineage, the newest checkpoint restored (span ``resume_read``) and
+  put on the device with the state's shardings (span ``resume_h2d``), the
+  digest cache loaded, the reader rebuilt at the saved cursor, and one
+  train step whose loss is read back: a job has resumed when its first
+  step returns.  That loss is compared with the loss the same step gave
+  without the interruption.
 
 After each GC round the harness reads the newest save back at its version
 (``bench.check.save_sample``) before the next steps change the state.  That
@@ -24,7 +32,7 @@ left out of the window.
 from __future__ import annotations
 
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +41,8 @@ from bench import check, gen
 from bench.trace import Spans
 
 # the host spans the operations and the checks write, by name
-SPAN_NAMES = ("train_step", "ckpt_save", "gc_round", "save_check")
+SPAN_NAMES = ("train_step", "ckpt_save", "gc_round", "save_check", "resume", "resume_read",
+              "resume_h2d")
 
 
 @jax.jit
@@ -68,6 +77,10 @@ class Job:
         self.first: Dict[str, object] = {}
         self.save_differ: List[int] = []   # per save read back after a GC round
         self.timeline: List[tuple] = []    # (op, seconds) of every operation run
+        self.newest: Optional[Tuple[object, dict]] = None   # (stats, reader) of the last save
+        self.step_no = 0                   # the state's step, kept on the host
+        self.step_losses: Dict[int, float] = {}   # each step's first loss, by step number
+        self.resume_gaps: List[float] = []  # per resume, its first loss against the unbroken run
         self.reset()
 
     def reset(self) -> None:
@@ -75,8 +88,8 @@ class Job:
         self.steps_done = 0
         self.tokens = 0
         self.saves: List[object] = []
-        self.save_readers: List[dict] = []
         self.gc_rounds = 0
+        self.resumes = 0
         self.failed = 0
         self.check_s = 0.0
 
@@ -86,7 +99,7 @@ class Job:
             t0 = time.perf_counter()
             getattr(self, "op_" + op["op"])(**{k: v for k, v in op.items() if k != "op"})
             self.timeline.append((op["op"], time.perf_counter() - t0))
-            if op["op"] == "gc" and self.saves:
+            if op["op"] == "gc" and self.newest is not None:
                 self._check_newest_save()
 
     def _check_newest_save(self) -> None:
@@ -104,6 +117,8 @@ class Job:
             loss = float(metrics["loss"])  # waits for the step
         self.steps_done += 1
         self.tokens += tokens.size
+        self.step_no += 1
+        self.step_losses.setdefault(self.step_no, loss)
         return loss
 
     def op_train_steps(self, n: int) -> None:
@@ -128,12 +143,49 @@ class Job:
         with self.spans.span("ckpt_save"):
             stats = s.ckpt.save(s.state, step=step, extra={"reader": reader})
         self.saves.append(stats)
-        self.save_readers.append(reader)
+        self.newest = (stats, reader)
 
     def op_gc(self) -> None:
         with self.spans.span("gc_round"):
             self.sys.gc_round()
         self.gc_rounds += 1
+
+    def op_resume(self) -> None:
+        with self.spans.span("resume"):
+            self.first_step_after(self.put_on_device(*self.read_back()))
+        self.resumes += 1
+
+    def read_back(self) -> Tuple[object, dict]:
+        """A resume's first half: the device state dropped and freed, a fresh
+        client and checkpointer, the newest checkpoint read to the host.
+        Returns the state as numpy leaves, and the manifest."""
+        s = self.sys
+        leaves = jax.tree.leaves(s.state)
+        s.state = None
+        for leaf in leaves:   # frees the device buffers now: two states do not fit
+            leaf.delete()
+        s.reopen()
+        with self.spans.span("resume_read"):
+            return s.ckpt.restore(s.abstract, with_manifest=True)
+
+    def put_on_device(self, restored, manifest: dict) -> dict:
+        """A resume's second half, up to its first step: the state put on the
+        device with its shardings, the digest cache loaded, the reader
+        rebuilt at the saved cursor.  Returns the manifest."""
+        s = self.sys
+        with self.spans.span("resume_h2d"):
+            s.state = jax.block_until_ready(jax.device_put(restored, s.shardings))
+        s.ckpt.load_digest_cache()
+        s.reader = s.reader_at(manifest["extra"].get("reader"))
+        self.step_no = manifest["step"]
+        return manifest
+
+    def first_step_after(self, manifest: dict) -> None:
+        """The resumed job's first step, its loss against the same step's
+        without the interruption (none recorded: an infinite gap)."""
+        want = self.step_losses.get(manifest["step"] + 1)
+        loss = self._step()
+        self.resume_gaps.append(abs(loss - want) / abs(want) if want is not None else float("inf"))
 
     # --------------------------------------------------------------- window
     def _elapsed(self, t0: float, c0: float) -> float:
@@ -154,4 +206,4 @@ class Job:
         return self._elapsed(t0, c0)
 
     def attempted(self) -> int:
-        return self.steps_done + len(self.saves) + self.gc_rounds
+        return self.steps_done + len(self.saves) + self.gc_rounds + self.resumes

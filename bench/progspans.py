@@ -33,6 +33,15 @@ def program_records() -> List[Record]:
     return recorded()
 
 
+def program_span_names() -> Tuple[str, ...]:
+    """The names the program's spans may take; none if it records no spans."""
+    try:
+        from repro.spans import SPAN_NAMES
+    except ImportError:
+        return ()
+    return tuple(SPAN_NAMES)
+
+
 def _root(rec: Record, by_id: dict) -> Record:
     while rec[1] is not None and rec[1] in by_id:
         rec = by_id[rec[1]]

@@ -1,20 +1,12 @@
-"""Plain references: the OLMo training step and the page digest.
+"""Plain references: the training step and the page digest.
 
 Nothing here imports the program.  The training reference is the
-architecture's forward pass, loss and AdamW update written out in
-``jax.numpy`` at float32 with ``highest`` matmul precision, from the
-configuration file alone:
-
-* non-parametric LayerNorm (eps 1e-5) before attention, before the MLP and
-  before the output head;
-* full causal multi-head attention with rotary embeddings (rotate-half,
-  theta from the file) on q and k, scaled by 1/sqrt(head size);
-* SwiGLU MLP: (silu(x·Wg) * (x·Wi))·Wo;
-* the output head multiplies by the tied embedding table;
-* loss: mean next-token cross-entropy plus ``z_loss``·mean(logsumexp²);
-* AdamW as the file's ``optimizer`` states: global-norm clipping, bias-
-  corrected moments, decoupled weight decay on every leaf, linear warmup
-  then cosine decay to ``min_lr_frac``.
+architecture's loss (its family module, ``bench.families``) and the AdamW
+update written out in ``jax.numpy`` at float32 with ``highest`` matmul
+precision, from the configuration file alone.  AdamW is as the file's
+``optimizer`` states: global-norm clipping, bias-corrected moments,
+decoupled weight decay on every leaf, linear warmup then cosine decay to
+``min_lr_frac``.
 
 ``precision="fp8"`` is the control: every matmul operand rounded to
 float8 e4m3 with one scale per tensor, the step below the bfloat16 that the
@@ -30,7 +22,6 @@ the host from the bytes read back.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Dict, List, Tuple
 
 import numpy as np
@@ -39,29 +30,10 @@ import jax
 import jax.numpy as jnp
 
 from bench import gen
+from bench.families import family
 
-HIGHEST = jax.lax.Precision.HIGHEST
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
-
-
-def param_shapes(cfg: dict) -> Dict[str, Tuple[tuple, object]]:
-    """The weights as the program stores them: layers stacked on axis 0."""
-    L, d, f, v = (cfg["num_hidden_layers"], cfg["hidden_size"],
-                  cfg["intermediate_size"], cfg["vocab_size"])
-    h, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = d // h
-    dt = jnp.dtype(cfg["dtype"])
-    return {
-        "embed/table": ((v, d), dt),
-        "groups/0/ffn/wg": ((L, d, f), dt),
-        "groups/0/ffn/wi": ((L, d, f), dt),
-        "groups/0/ffn/wo": ((L, f, d), dt),
-        "groups/0/mixer/wk": ((L, d, hkv, dh), dt),
-        "groups/0/mixer/wo": ((L, h, dh, d), dt),
-        "groups/0/mixer/wq": ((L, d, h, dh), dt),
-        "groups/0/mixer/wv": ((L, d, hkv, dh), dt),
-    }
 
 
 def _q(x, precision: str):
@@ -74,57 +46,6 @@ def _q(x, precision: str):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _ln(x):
-    mean = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), -1, keepdims=True)
-    return (x - mean) * jax.lax.rsqrt(var + 1e-5)
-
-
-def _rope(x, theta: float):
-    d = x.shape[-1]
-    half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def loss_fn(p, tokens, labels, cfg: dict, precision: str = "f32"):
-    Q = functools.partial(_q, precision=precision)
-    ein = functools.partial(jnp.einsum, precision=HIGHEST)
-    theta = cfg["rope_theta"]
-
-    def layer(x, w):
-        wq, wk, wv, wo, wg, wi, w2 = w
-        h = _ln(x)
-        q = _rope(ein("btd,dhk->bhtk", Q(h), Q(wq)), theta)
-        k = _rope(ein("btd,dhk->bhtk", Q(h), Q(wk)), theta)
-        v = ein("btd,dhk->bhtk", Q(h), Q(wv))
-        s = ein("bhqd,bhkd->bhqk", Q(q), Q(k)) / math.sqrt(q.shape[-1])
-        t = s.shape[-1]
-        causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
-        a = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-        o = ein("bhqk,bhkd->bhqd", Q(a), Q(v))
-        x = x + ein("bhtk,hkd->btd", Q(o), Q(wo))
-        h = _ln(x)
-        u = jax.nn.silu(ein("btd,df->btf", Q(h), Q(wg))) * ein("btd,df->btf", Q(h), Q(wi))
-        return x + ein("btf,fd->btd", Q(u), Q(w2))
-
-    table = p["embed/table"]
-    x = table[tokens]
-    for l in range(cfg["num_hidden_layers"]):
-        w = tuple(p[k][l] for k in ("groups/0/mixer/wq", "groups/0/mixer/wk",
-                                    "groups/0/mixer/wv", "groups/0/mixer/wo",
-                                    "groups/0/ffn/wg", "groups/0/ffn/wi",
-                                    "groups/0/ffn/wo"))
-        x = jax.checkpoint(layer)(x, w)
-    logits = ein("btd,vd->btv", Q(_ln(x)), Q(table))
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - gold) + cfg["z_loss"] * jnp.mean(jnp.square(lse))
-
-
 def _norms(tree):
     return {k: jnp.sqrt(jnp.sum(jnp.square(v))) for k, v in tree.items()}
 
@@ -134,7 +55,8 @@ def _norms(tree):
 def _adam_step(master, mu, nu, t, tokens, labels, cfg_items, precision):
     cfg = dict(cfg_items)
     opt = dict(cfg["optimizer"])
-    loss, g = jax.value_and_grad(loss_fn)(master, tokens, labels, cfg, precision)
+    loss, g = jax.value_and_grad(family(cfg).loss_fn)(
+        master, tokens, labels, cfg, functools.partial(_q, precision=precision))
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in g.values()))
     scale = jnp.minimum(1.0, opt["clip_norm"] / (gnorm + 1e-9))
     g = {k: x * scale for k, x in g.items()}
@@ -154,7 +76,9 @@ def _adam_step(master, mu, nu, t, tokens, labels, cfg_items, precision):
 
 @functools.partial(jax.jit, static_argnames=("cfg_items", "precision"))
 def _loss(master, tokens, labels, cfg_items, precision):
-    return loss_fn(master, tokens, labels, dict(cfg_items), precision)
+    cfg = dict(cfg_items)
+    return family(cfg).loss_fn(master, tokens, labels, cfg,
+                               functools.partial(_q, precision=precision))
 
 
 def _freeze(d):
@@ -162,16 +86,11 @@ def _freeze(d):
                   tuple(v) if isinstance(v, list) else v) for k, v in sorted(d.items()))
 
 
-MODEL_KEYS = ("num_hidden_layers", "hidden_size", "intermediate_size", "vocab_size",
-              "num_attention_heads", "num_key_value_heads", "rope_theta", "z_loss",
-              "optimizer")
-
-
 def train_numbers(cfg: dict, seed: int, batches: List[Tuple[np.ndarray, np.ndarray]],
                   precision: str = "f32", fault: str = "") -> dict:
     """Losses of each step, the first step's clipped gradient norm per leaf,
     and each leaf's change after all steps, from the seed's initial weights."""
-    shapes = param_shapes(cfg)
+    shapes = family(cfg).param_shapes(cfg)
 
     def start():
         return {k: v.astype(jnp.float32) for k, v in
@@ -180,7 +99,7 @@ def train_numbers(cfg: dict, seed: int, batches: List[Tuple[np.ndarray, np.ndarr
     master = start()
     mu = {k: jnp.zeros_like(v) for k, v in master.items()}
     nu = {k: jnp.zeros_like(v) for k, v in master.items()}
-    items = _freeze({k: cfg[k] for k in MODEL_KEYS})
+    items = _freeze(cfg)
     losses, grad1 = [], None
     for t, (tokens, labels) in enumerate(batches, start=1):
         tokens, labels = np.array(tokens), np.array(labels)

@@ -39,6 +39,7 @@ import jax  # noqa: E402
 
 from bench import check, gen, reference  # noqa: E402
 from bench.peaks import Peak, peak_for  # noqa: E402
+from bench.progspans import program_span_names  # noqa: E402
 from bench.trace import Spans, TraceSummary, capture, reduce  # noqa: E402
 
 
@@ -171,19 +172,20 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     print(f"[phase] set-up {setup_s:.3f} s, {counter.count} programs ({counter.seconds:.3f} s), "
           f"{counter.misses} compiled; " + ", ".join(f"{op} {t:.3f}" for op, t in job.timeline),
           file=sys.stderr, flush=True)
-    n_compiles, n_misses = counter.count, counter.misses
+    n_compiles, n_misses, n_ops = counter.count, counter.misses, len(job.timeline)
     summary = None
     if trace:
         with tempfile.TemporaryDirectory(prefix="bench-trace-") as d:
             window_s, events = capture(lambda: job.cycles(traffic["trace_cycles"]), d,
-                                       loop.SPAN_NAMES)
+                                       loop.SPAN_NAMES + program_span_names())
         if dump_events:
             Path(dump_events).write_text(json.dumps(events))
         summary = reduce(events)
     else:
         window_s = job.window(seconds)
     print(f"[phase] window {window_s:.3f} s, {counter.count - n_compiles} programs, "
-          f"{counter.misses - n_misses} compiled",
+          f"{counter.misses - n_misses} compiled; "
+          + ", ".join(f"{op} {t:.3f}" for op, t in job.timeline[n_ops:]),
           file=sys.stderr, flush=True)
 
     host_gib = host_peak_gib()
@@ -202,13 +204,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
+    attempted = job.attempted()
     t_check = time.perf_counter()
     checks = correctness(cell, job, seed)
     print(f"[phase] check {time.perf_counter() - t_check:.3f} s", file=sys.stderr, flush=True)
     device = {"platform": used[0].platform, "kind": used[0].device_kind,
               "count": len(used), "memory_peak_bytes": mem_peak}
     out = {"correct": all(c["value"] <= c["limit"] for c in checks.values()),
-           "attempted": job.attempted(), "failed": job.failed,
+           "attempted": attempted, "failed": job.failed,
            "metrics": metrics, "device": device}
     if summary is not None:
         device["busy_s"] = summary.busy_s
@@ -223,8 +226,17 @@ def correctness(cell: Cell, job, seed: int) -> Dict[str, Dict[str, float]]:
     """Every number compared, beside its limit (see ``bench.check``)."""
     cfg, limits = cell.cfg, cell.cfg["limits"]
     values: Dict[str, float] = {"save_readback_bytes_differ": sum(job.save_differ)}
+    read = None
+    if job.resume_gaps:   # one more resume, outside the window; its read is the read-back's
+        read = job.read_back()
+        manifest = job.put_on_device(*read)
+        values["resume_bytes_differ"] = check.resumed_state(job, manifest)
     values["readback_bytes_differ"], values["digest_pages_differ"] = check.readback(
-        job, seed, cfg["store"]["page_bytes"])
+        job, seed, cfg["store"]["page_bytes"], read)
+    if read is not None:
+        del read
+        job.first_step_after(manifest)
+        values["resume_loss_gap"] = max(job.resume_gaps)
     prog = job.first
     job.sys.state = None   # the program's state is freed before the reference runs
     gc.collect()

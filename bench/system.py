@@ -9,7 +9,6 @@ benchmark adds is the seed: the corpus and the initial state come from
 
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -18,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.checkpoint import BlobCheckpointer
 from repro.checkpoint.blobckpt import header_pages_for
-from repro.configs import get_config
 from repro.core import BlobSeerService, collect_garbage
 from repro.data import CorpusWriter, ShardedReader
 from repro.launch.mesh import make_mesh
@@ -27,30 +25,9 @@ from repro.train.optimizer import AdamWConfig
 from repro.train.step import TrainStepBuilder
 
 from bench import gen
+from bench.families import family
 
 CORPUS_APPEND_TOKENS = 1 << 16   # tokens per corpus append
-
-
-def model_config(cfg: dict):
-    """The program's model config with the file's sizes; refuses a file the
-    program cannot run as stated."""
-    base = get_config(cfg["program_arch"])
-    heads = cfg["num_attention_heads"]
-    mc = dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], vocab_size=cfg["vocab_size"],
-        d_model=cfg["hidden_size"], n_heads=heads,
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        d_head=cfg["hidden_size"] // heads, dtype=cfg["dtype"])
-    stated = {
-        "norm_kind": "nonparam_ln", "mlp_kind": "swiglu" if cfg["hidden_act"] == "silu" else None,
-        "tie_embeddings": cfg["tie_word_embeddings"], "rope_theta": cfg["rope_theta"],
-        "qkv_bias": cfg["attention_bias"], "qk_norm": False, "window": None,
-        "block_pattern": ("attn",), "n_experts": 0,
-    }
-    for key, want in stated.items():
-        if getattr(mc, key) != want:
-            raise ValueError(f"program config {key}={getattr(mc, key)!r}, file states {want!r}")
-    return mc
 
 
 @dataclass
@@ -68,6 +45,20 @@ class System:
     def gc_round(self) -> dict:
         return collect_garbage(self.svc, orphan_grace=None)
 
+    def reopen(self) -> None:
+        """A restarted trainer's own client, and a checkpointer on the same
+        lineage, opened as ``train.main`` opens them: nothing the old
+        client cached carries over."""
+        self.client = self.svc.client("trainer")
+        self.ckpt = BlobCheckpointer(self.client, self.ckpt.blob_id, psize=self.ckpt.psize,
+                                     header_pages=self.ckpt.header_bytes // self.ckpt.psize)
+
+    def reader_at(self, state: Optional[dict]) -> ShardedReader:
+        """The corpus reader again, at a saved cursor."""
+        r = self.reader
+        return ShardedReader(self.client, r.blob_id, batch=r.batch, seq_len=r.seq_len,
+                             state=state)
+
 
 def build(cfg: dict, seed: int, corpus_tokens: int = 0) -> System:
     """Service, checkpoint lineage, the step, the corpus and the reader;
@@ -77,7 +68,7 @@ def build(cfg: dict, seed: int, corpus_tokens: int = 0) -> System:
                           n_meta_shards=store["meta_shards"],
                           data_replication=store["replication"])
     client = svc.client("trainer")
-    model = build_model(model_config(cfg))
+    model = build_model(family(cfg).model_config(cfg))
     mesh = make_mesh((1, 1), ("data", "model"))
     builder = TrainStepBuilder(model, mesh, strategy="tp",
                                opt=AdamWConfig(**cfg.get("optimizer", {})),

@@ -10,6 +10,7 @@ import jax
 import pytest
 
 from bench import gen, loop, reference, run
+from bench.families import family
 from bench.tests.tiny import cell_from_files, run_tiny, tiny_cell
 from repro.core.blob import BlobClient
 from repro.train.step import TrainStepBuilder
@@ -33,9 +34,21 @@ def _patch_step(monkeypatch, wrap):
     monkeypatch.setattr(TrainStepBuilder, "jit_train_step", jit_train_step)
 
 
+# what the harness read on this tiny cell at run_tiny's seed before the
+# architecture moved into bench/families: the move changes no reading
+BEFORE_FAMILIES = {
+    "save_readback_bytes_differ": 0, "readback_bytes_differ": 0, "digest_pages_differ": 0,
+    "loss1_gap": 1.8033609122212927e-05, "loss2_gap": 1.7812654905115888e-05,
+    "grad1_gap": 0.00030623322768642136, "change3_gap": 0.00024696273654990563,
+}
+
+
 def test_sound_run_is_correct():
-    out = run_tiny(tiny_cell(cell_from_files(*TRAIN)))
+    cell = tiny_cell(cell_from_files(*TRAIN))
+    out = run_tiny(cell)
     assert out["correct"], out["checks"]
+    assert {k: c["value"] for k, c in out["checks"].items()} == BEFORE_FAMILIES
+    assert family(cell.cfg).train_flops_per_token(cell.cfg, cell.cfg["seq"]) == 737280.0
 
 
 def test_step_returning_its_state_unchanged(monkeypatch):

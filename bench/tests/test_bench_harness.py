@@ -12,7 +12,9 @@ from pathlib import Path
 
 import pytest
 
+import bench.families
 from bench import run
+from bench.families import family, known, olmo
 from bench.tests.tiny import cell_from_files, run_tiny, tiny_cell
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -43,12 +45,40 @@ def _digests(root: Path) -> dict:
             for p in root.rglob("*") if p.is_file()}
 
 
-def test_new_cell_is_files_and_entries_only(tmp_path):
+@pytest.fixture
+def families_path(monkeypatch):
+    """Lets a test put one more directory on ``bench.families``' path."""
+    def add(directory: Path) -> None:
+        monkeypatch.setattr(bench.families, "__path__",
+                            [*bench.families.__path__, str(directory)])
+    yield add
+    sys.modules.pop("bench.families.probe", None)
+
+
+def test_family_is_found_by_model_type(tmp_path, families_path):
+    assert family({"model_type": "olmo"}) is olmo
+    # the families present are named, whichever a later configuration adds
+    with pytest.raises(KeyError, match=r"'probe'.*known: \[.*'olmo'.*\]"):
+        family({"model_type": "probe"})
+    (tmp_path / "probe.py").write_text("WIDTH = 7\n")
+    families_path(tmp_path)
+    assert "probe" in known()
+    assert family({"model_type": "probe"}).WIDTH == 7
+
+
+def test_new_cell_is_files_and_entries_only(tmp_path, families_path):
     _copy_benchmark(tmp_path)
     before = _digests(tmp_path)
     bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
 
+    # a configuration of another model_type: its family is a new module
+    (tmp_path / "bench/families/probe.py").write_text(
+        '"""OLMo\'s layers under another model_type."""\n'
+        "from bench.families.olmo import (loss_fn, model_config, n_params, param_shapes,\n"
+        "                                 train_flops_per_token)\n")
+    families_path(tmp_path / "bench" / "families")
     cfg = json.loads((tmp_path / "bench/configs/olmo1b-l4.train-state.json").read_text())
+    cfg["model_type"] = "probe"
     (tmp_path / "bench/configs/probe.json").write_text(json.dumps(cfg))
     (tmp_path / "bench/traffic/steps-then-save.json").write_text(json.dumps({
         "corpus_tokens": 4096,
@@ -76,6 +106,7 @@ def test_new_cell_is_files_and_entries_only(tmp_path):
 
     cell = tiny_cell(run.load_cell("probe.steps", root=tmp_path), steps_per_cycle=2)
     assert cell.root == tmp_path
+    assert family(cell.cfg).__file__ == str(tmp_path / "bench/families/probe.py")
     out = run_tiny(cell, trace=True)
     assert out["correct"], out["checks"]
     assert out["metrics"]["steps_done"]["value"] == 2.0

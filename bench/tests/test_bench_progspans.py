@@ -49,6 +49,9 @@ def test_tiny_traced_run_reports_the_program_phases(monkeypatch):
               "store_pages_s", "store_publish_s"]
     assert 0 < sum(got[n] for n in phases) <= save_s
     assert 0 < got["gc_mark_s"] + got["gc_sweep_s"] <= got["gc_s"]
+    # idle gaps are named by the program's phases where one covers them
+    named = {n for n, _ in out["breakdown"]["idle_gaps"]}
+    assert named & set(progspans.program_span_names()), named
 
 
 def _run(bench_spans, trace=None):

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from bench import flops, reference
+from bench.families import olmo
 from bench.peaks import peak_for
 from bench.trace import WINDOW, covered, gaps, merge, reduce
 
@@ -64,9 +65,9 @@ def test_reduce_small_recorded_trace():
 def test_flops_per_token_by_hand():
     # 4 layers: attention 4·2048² and SwiGLU 3·2048·8192 each, plus the
     # 50304×2048 tied table: 371,458,048 parameters
-    assert flops.n_params(CFG) == 4 * (4 * 2048 * 2048 + 3 * 2048 * 8192) + 50304 * 2048
-    assert flops.n_params(CFG) == 371_458_048
-    assert flops.train_flops_per_token(CFG, 2048) == 6 * 371_458_048 + 12 * 4 * 2048 * 2048
+    assert olmo.n_params(CFG) == 4 * (4 * 2048 * 2048 + 3 * 2048 * 8192) + 50304 * 2048
+    assert olmo.n_params(CFG) == 371_458_048
+    assert olmo.train_flops_per_token(CFG, 2048) == 6 * 371_458_048 + 12 * 4 * 2048 * 2048
 
 
 def test_digest_bytes_by_hand():
