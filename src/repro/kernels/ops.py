@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.kernels import ref as _ref
 from repro.kernels.delta_mask import delta_mask_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.grouped_matmul import grouped_matmul_pallas
 from repro.kernels.linear_scan import linear_scan_pallas
 from repro.kernels.page_digest import page_digest_pallas
 
@@ -215,3 +216,17 @@ def linear_scan(a: jax.Array, x: jax.Array) -> jax.Array:
     if use_pallas():
         return linear_scan_pallas(a, x, interpret=_interpret())
     return _linear_scan_ref(a, x)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the expert layer)
+# ---------------------------------------------------------------------------
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """lhs (m, k) rows sorted by group, rhs (groups, k, n) -> (m, n): group g's
+    rows times ``rhs[g]``, zeros past the last group.  Differentiable."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if use_pallas():
+        return grouped_matmul_pallas(lhs, rhs, group_sizes, _interpret())
+    return _ref.ref_grouped_matmul(lhs, rhs, group_sizes)

@@ -99,3 +99,10 @@ def ref_linear_scan(a: jax.Array, x: jax.Array, h0: jax.Array | None = None) -> 
 
     _, h = jax.lax.associative_scan(combine, (a, x), axis=1)
     return h
+
+
+def ref_grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """lhs (m, k) rows sorted by group, rhs (groups, k, n) -> (m, n): rows
+    ``[start_g, start_g + group_sizes[g])`` times ``rhs[g]``, zeros past the
+    last group (``lax.ragged_dot``, in ``lhs``'s dtype)."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype)

@@ -37,7 +37,7 @@ from repro.models.config import ModelConfig
 
 ATTN_KINDS = ("attn", "local", "swa")
 MLSTM_CHUNK = 256
-BLOCKWISE_THRESHOLD = 4096  # must match models.layers
+BLOCKWISE_THRESHOLD = 2048  # must match models.layers
 
 
 def _mesh_factor(mesh: Mesh, axes) -> int:
@@ -108,21 +108,20 @@ def _mlp_flops(cfg: ModelConfig) -> float:
 
 
 def _moe_flops(cfg: ModelConfig, T: int) -> Dict[str, float]:
-    """Per-token flops for one MoE layer (capacity-based einsum impl).
+    """Per-token flops for one dropless MoE layer (``models/moe.py``).
 
-    Routing-group size g (= seq_len unless cfg.moe_group re-groups):
-    capacity C = cf·K·g/E, so the dispatch/combine einsums cost
-    2·E·C·d = 2·K·cf·g·d per token — linear in g, the §Perf lever.
+    The router scores every expert: 2·d·E.  The held experts' SwiGLU runs
+    on the (token, choice) pairs routed to them.  On a TPU the grouped
+    matmul kernel multiplies those pairs alone, k·held/E a token under
+    uniform routing; off the TPU its reference (``lax.ragged_dot``)
+    multiplies every pair by every held expert's weights and masks the
+    result, k·held a token.  The sort and the permutations are gathers.
     """
-    d, f, E, K, cf = (cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.top_k,
-                      cfg.capacity_factor)
-    g = T if cfg.moe_group is None or T <= cfg.moe_group else cfg.moe_group
-    C = max(1, int(cf * K * g / E))
-    experts = 2 * 3 * d * f * (E * C / g)       # slots incl. padding
-    router = 2 * d * E
-    dispatch = 2 * 2 * E * C * d
-    return {"moe_experts": experts, "moe_router": router,
-            "moe_dispatch": dispatch}
+    from repro.kernels import ops as kops
+
+    d, f, E, K, held = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.top_k, cfg.n_held
+    pairs = K * held / E if kops.use_pallas() else K * held
+    return {"moe_experts": 2 * 3 * d * f * pairs, "moe_router": 2 * d * E}
 
 
 def _rglru_flops(cfg: ModelConfig) -> float:
@@ -225,7 +224,6 @@ def _shard_factors(cfg: ModelConfig, mesh: Mesh, batch: int,
         "mlp": bshard * (eff(cfg.d_ff) if cfg.d_ff else 1),
         "moe_experts": bshard * eff(cfg.n_experts),
         "moe_router": bshard,
-        "moe_dispatch": bshard * eff(cfg.n_experts),
         "recurrent": bshard * eff(cfg.rnn_width),
         "logits": bshard * eff(cfg.vocab_size),
         "encoder": bshard * eff(cfg.n_heads),
@@ -326,8 +324,8 @@ def cell_costs(
             bytes_dev += p_local                         # resident gathered copy
         act = 20.0 * b_micro * T * d * esz * L  # activations (not model-sharded)
         bytes_dev += accum * act
-        if cfg.moe:
-            disp = 2.0 * b_micro * T * (cfg.top_k * cfg.capacity_factor * T) * esz
+        if cfg.moe:   # the pairs permuted to the experts and back
+            disp = 2.0 * b_micro * T * cfg.top_k * d * esz
             bytes_dev += accum * disp * cfg.n_layers / _div(cfg.n_experts, tp)
         bytes_dev += accum * b_micro * T * cfg.vocab_size * esz / _div(cfg.vocab_size, tp) * 2
     elif cell.step == "prefill":
@@ -387,8 +385,7 @@ def cell_costs(
         elif dp > 1:
             coll += 2.0 * esz * cfg.param_count() / tp  # grad all-reduce (ring)
         if cfg.moe and _div(cfg.n_experts, tp) > 1:
-            C = max(1, int(cfg.capacity_factor * cfg.top_k * T / cfg.n_experts))
-            a2a = b_micro * cfg.n_experts * C * d * esz
+            a2a = b_micro * T * cfg.top_k * d * esz     # every pair to its expert
             coll += accum * cfg.n_layers * 2 * 2 * a2a / tp
     else:
         b_loc = max(B // dp, 1)
@@ -408,9 +405,7 @@ def cell_costs(
                 elif kind in ("local", "swa"):
                     coll += 2.0 * b_loc * cfg.n_heads * win_cache * 4
         if cfg.moe and _div(cfg.n_experts, tp) > 1:
-            C = max(1, int(cfg.capacity_factor * cfg.top_k * max(t_q, 1)
-                           / cfg.n_experts))
-            coll += cfg.n_layers * 2 * (b_loc * cfg.n_experts * C * d * esz) / tp
+            coll += cfg.n_layers * 2 * (b_loc * max(t_q, 1) * cfg.top_k * d * esz) / tp
 
     return CellCosts(
         flops_per_device=flops_dev,

@@ -39,14 +39,10 @@ from repro.launch.mesh import make_production_mesh
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, strategy: str,
              out_dir: str, remat: str = "full", accum=None,
-             moe_group=None, tag_suffix: str = "") -> dict:
-    import dataclasses
-
+             tag_suffix: str = "") -> dict:
     from repro.launch.specs import build_cell  # after XLA_FLAGS
 
     cfg = get_config(arch)
-    if moe_group is not None:
-        cfg = dataclasses.replace(cfg, moe_group=moe_group)
     cell = SHAPES[shape_name]
     ok, why = applicable(cfg, cell)
     if not ok:
@@ -134,7 +130,6 @@ def main() -> None:
     ap.add_argument("--strategy", default="auto")
     ap.add_argument("--remat", default="full")
     ap.add_argument("--accum", type=int, default=None)
-    ap.add_argument("--moe-group", type=int, default=None)
     ap.add_argument("--tag-suffix", default="")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
@@ -149,7 +144,7 @@ def main() -> None:
             for mesh_kind in meshes:
                 results.append(run_cell(arch, shape, mesh_kind, args.strategy,
                                         args.out, args.remat, args.accum,
-                                        args.moe_group, args.tag_suffix))
+                                        args.tag_suffix))
     n_ok = sum(r["status"] == "ok" for r in results)
     n_skip = sum(r["status"] == "skipped" for r in results)
     n_fail = sum(r["status"] == "fail" for r in results)

@@ -26,25 +26,26 @@ class ModelConfig:
 
     # -- attention flavour ---------------------------------------------------
     window: Optional[int] = None     # sliding-window size (SWA / local attn)
-    qk_norm: bool = False            # per-head RMSNorm on q,k (qwen3)
+    qk_norm: bool = False            # RMSNorm with a learned scale on q and k, before RoPE
+    qk_norm_width: str = "head"      # "head": over each head (qwen3) | "full": over the whole projection (olmoe)
     qkv_bias: bool = False           # bias on qkv projections (qwen1.5)
     rope_theta: float = 10_000.0
     softcap: Optional[float] = None
 
     # -- norms / mlp ----------------------------------------------------------
     norm_kind: str = "rmsnorm"       # rmsnorm | layernorm | nonparam_ln
+    norm_eps: Optional[float] = None  # None: 1e-6 for RMSNorm and QK-norm, 1e-5 for LayerNorm
     mlp_kind: str = "swiglu"         # swiglu | geglu | gelu
     tie_embeddings: bool = False
 
     # -- MoE -------------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0               # the router's outputs: every expert of a layer
     top_k: int = 0
-    capacity_factor: float = 1.25
-    # routing-group size: dispatch/combine einsums cost O(group * E * C)
-    # per token with C ∝ group, i.e. quadratic in the group — None groups
-    # per batch row (group = seq_len, the naive GShard layout); the perf
-    # pass re-groups to a few hundred tokens (see EXPERIMENTS.md §Perf).
-    moe_group: Optional[int] = None
+    norm_topk_prob: bool = True      # renormalise the top-k router weights to sum to 1
+    # expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of each layer (None: all of them)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
 
     # -- layer pattern (cycled; heterogeneous for hybrid/ssm) -------------------
     # entries: "attn" | "local" | "swa" | "rglru" | "mlstm" | "slstm"
@@ -76,6 +77,11 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def n_held(self) -> int:
+        """Experts of each layer whose weights live here."""
+        return self.n_experts if self.experts_held is None else self.experts_held
+
+    @property
     def attention_free(self) -> bool:
         return all(k in ("rglru", "mlstm", "slstm") for k in self.block_pattern)
 
@@ -95,7 +101,7 @@ class ModelConfig:
         else:
             mlp = 2 * d * self.d_ff
         if self.moe:
-            mlp = self.n_experts * (3 * d * self.d_ff) + d * self.n_experts
+            mlp = self.n_held * (3 * d * self.d_ff) + d * self.n_experts
         rnn = self.rnn_width
         rec = 2 * d * rnn + rnn * d + self.conv_width * rnn + 3 * rnn  # griffin-ish
         mls = 2 * d * 2 * rnn + 2 * rnn * d + (3 + 3) * rnn            # mlstm-ish
@@ -124,7 +130,7 @@ class ModelConfig:
             return self.param_count()
         d = self.d_model
         full = self.param_count()
-        moe_all = self.n_layers * self.n_experts * 3 * d * self.d_ff
+        moe_all = self.n_layers * self.n_held * 3 * d * self.d_ff
         moe_active = self.n_layers * self.top_k * 3 * d * self.d_ff
         return full - moe_all + moe_active
 

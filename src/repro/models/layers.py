@@ -2,7 +2,8 @@
 
 Everything is a pure function over explicit parameter dicts (leaves
 created with ``param_util.leaf`` carry logical sharding axes).  Covers
-the dense-family variance across the assigned archs: qk-norm (qwen3),
+the dense-family variance across the assigned archs: qk-norm per head
+(qwen3) or over the whole projection (olmoe),
 QKV bias (qwen1.5), non-parametric LN (olmo), SWA (danube3), local
 attention (recurrentgemma), GQA everywhere.
 """
@@ -21,12 +22,12 @@ from repro.models.param_util import leaf, normal, ones, zeros
 
 # Blockwise attention kicks in above this many kv positions (keeps the
 # 32k prefill cells inside per-device memory without a Pallas dependency
-# in the differentiable path).  Overridable: materializing 4k x 4k f32
-# scores is the peak-memory term for wide-head archs at train_4k
-# (EXPERIMENTS.md §Perf qwen1.5).
+# in the differentiable path).  Overridable: materializing the f32 scores
+# of 4k positions is the peak-memory term of a 4k training step (16 heads
+# of OLMoE at 4096 keep 1 GiB of scores a layer for the backward pass).
 import os as _os
 
-BLOCKWISE_KV_THRESHOLD = int(_os.environ.get("REPRO_BLOCKWISE_THRESHOLD", 4096))
+BLOCKWISE_KV_THRESHOLD = int(_os.environ.get("REPRO_BLOCKWISE_THRESHOLD", 2048))
 BLOCKWISE_CHUNK = int(_os.environ.get("REPRO_BLOCKWISE_CHUNK", 1024))
 
 
@@ -48,15 +49,20 @@ def init_norm(cfg: ModelConfig, dtype) -> Dict:
     raise ValueError(cfg.norm_kind)
 
 
+def rms_eps(cfg: ModelConfig) -> float:
+    """The eps of RMSNorm and of QK-norm: the configuration's, else 1e-6."""
+    return 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+
+
 def apply_norm(p: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     dt = x.dtype
     xf = x.astype(jnp.float32)
     if cfg.norm_kind == "rmsnorm":
-        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
+        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + rms_eps(cfg))
         return (xf * p["scale"]).astype(dt)
     mean = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean((xf - mean) ** 2, -1, keepdims=True)
-    xf = (xf - mean) * jax.lax.rsqrt(var + 1e-5)
+    xf = (xf - mean) * jax.lax.rsqrt(var + (1e-5 if cfg.norm_eps is None else cfg.norm_eps))
     if cfg.norm_kind == "layernorm":
         xf = xf * p["scale"] + p["bias"]
     return xf.astype(dt)
@@ -128,15 +134,27 @@ def init_attention(rng, cfg: ModelConfig, dtype, cross: bool = False) -> Dict:
         p["bk"] = leaf(zeros((hkv, dh), dtype), "kv_heads", "head")
         p["bv"] = leaf(zeros((hkv, dh), dtype), "kv_heads", "head")
     if cfg.qk_norm and not cross:
-        p["q_scale"] = leaf(ones((dh,), jnp.float32), "head")
-        p["k_scale"] = leaf(ones((dh,), jnp.float32), "head")
+        if cfg.qk_norm_width == "full":
+            p["q_norm"] = leaf(ones((h, dh), jnp.float32), "q_heads", "head")
+            p["k_norm"] = leaf(ones((hkv, dh), jnp.float32), "kv_heads", "head")
+        else:
+            p["q_scale"] = leaf(ones((dh,), jnp.float32), "head")
+            p["k_scale"] = leaf(ones((dh,), jnp.float32), "head")
     return p
 
 
-def _head_rmsnorm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def _head_rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype)
+
+
+def _full_rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm of each token's whole projection, all heads together:
+    x (B, H, T, Dh), scale (H, Dh)."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, (1, 3), keepdims=True) + eps)
+    return (xf * scale[None, :, None, :]).astype(x.dtype)
 
 
 def _project_qkv(p, cfg, x, positions, apply_rope: bool = True):
@@ -148,8 +166,11 @@ def _project_qkv(p, cfg, x, positions, apply_rope: bool = True):
         k = k + p["bk"][None, :, None, :]
         v = v + p["bv"][None, :, None, :]
     if "q_scale" in p:
-        q = _head_rmsnorm(q, p["q_scale"])
-        k = _head_rmsnorm(k, p["k_scale"])
+        q = _head_rmsnorm(q, p["q_scale"], rms_eps(cfg))
+        k = _head_rmsnorm(k, p["k_scale"], rms_eps(cfg))
+    if "q_norm" in p:
+        q = _full_rmsnorm(q, p["q_norm"], rms_eps(cfg))
+        k = _full_rmsnorm(k, p["k_norm"], rms_eps(cfg))
     if apply_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
@@ -160,8 +181,10 @@ def _blockwise_attention(q, k, v, *, causal, window, q_offset, softcap, chunk):
     """Online-softmax attention, chunked over KV (pure jnp, differentiable).
 
     Memory O(Tq * chunk) per head instead of O(Tq * Tk): the 32k cells
-    and the remat policy rely on this.  Mirrors ``kernels/flash_attention``
-    (which serves the non-differentiable TPU serving path).
+    and the remat policy rely on this.  The backward pass recomputes each
+    chunk's scores instead of keeping them, so training keeps O(Tq * D)
+    per chunk too.  Mirrors ``kernels/flash_attention`` (which serves the
+    non-differentiable TPU serving path).
     """
     B, Hq, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
@@ -204,7 +227,7 @@ def _blockwise_attention(q, k, v, *, causal, window, q_offset, softcap, chunk):
     l0 = jnp.zeros((B, Hkv, group, Tq), jnp.float32)
     a0 = jnp.zeros((B, Hkv, group, Tq, D), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
-        step,
+        jax.checkpoint(step),
         (m0, l0, a0),
         (kc.transpose(2, 0, 1, 3, 4), vc.transpose(2, 0, 1, 3, 4),
          jnp.arange(n_chunks)),

@@ -32,6 +32,8 @@ from repro.models.config import ModelConfig
 from repro.models.param_util import leaf, normal, split_tree, stack_trees
 
 ATTN_KINDS = ("attn", "local", "swa")
+Z_LOSS = 1e-4      # weight of mean(logsumexp²) of the output logits
+AUX_LOSS = 1e-2    # weight of the MoE load-balancing loss, summed over layers
 
 
 def _dtype(cfg: ModelConfig):
@@ -71,7 +73,9 @@ def apply_block(
     positions: jax.Array,
     cache: Optional[Dict],
 ):
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, stats): ``stats["aux"]`` is the MoE
+    load-balancing loss (0 without experts), ``stats["expert_rows"]`` the
+    pairs routed to the held experts (MoE only)."""
     h = L.apply_norm(p["norm1"], cfg, x)
     if kind in ATTN_KINDS:
         y, new_cache = L.apply_attention(
@@ -86,16 +90,17 @@ def apply_block(
     else:
         raise ValueError(kind)
     x = x + y
-    aux = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     if "ffn" in p:
         h2 = L.apply_norm(p["norm2"], cfg, x)
         if cfg.moe:
-            y2, aux = M.apply_moe(p["ffn"], cfg, h2)
+            y2, aux, rows = M.apply_moe(p["ffn"], cfg, h2)
+            stats = {"aux": aux, "expert_rows": rows}
         else:
             y2 = L.apply_mlp(p["ffn"], cfg, h2)
         x = x + y2
     x = constrain(x, "batch", None, "embed_act")
-    return x, new_cache, aux
+    return x, new_cache, stats
 
 
 def init_cache_entry(cfg: ModelConfig, kind: str, batch: int, max_len: int):
@@ -186,24 +191,29 @@ def _remat(fn, policy: str):
 
 
 def apply_stack_train(params, cfg: ModelConfig, x, positions, remat_policy="none"):
-    """Training/prefill-style pass without caches. Returns (x, aux)."""
+    """Training/prefill-style pass without caches. Returns (x, stats): each
+    of ``apply_block``'s stats summed over layers."""
     n_groups, rest = _pattern_layout(cfg)
-    aux_total = jnp.zeros((), jnp.float32)
+    total = {"aux": jnp.zeros((), jnp.float32)}
+
+    def add(acc, stats):
+        return {k: acc[k] + v if k in acc else v for k, v in stats.items()}
+
     if n_groups > 0:
         def group_body(x, group_params):
-            aux = jnp.zeros((), jnp.float32)
+            acc = {"aux": jnp.zeros((), jnp.float32)}
             for p_idx, kind in enumerate(cfg.block_pattern):
-                x, _, a = apply_block(group_params[p_idx], cfg, kind, x, positions, None)
-                aux = aux + a
-            return x, aux
+                x, _, s = apply_block(group_params[p_idx], cfg, kind, x, positions, None)
+                acc = add(acc, s)
+            return x, acc
 
         body = _remat(group_body, remat_policy)
-        x, auxs = jax.lax.scan(lambda c, xs: body(c, xs), x, tuple(params["groups"]))
-        aux_total = aux_total + auxs.sum()
+        x, stats = jax.lax.scan(lambda c, xs: body(c, xs), x, tuple(params["groups"]))
+        total = add(total, {k: s.sum() for k, s in stats.items()})
     for p_rest, kind in zip(params["rest"], _pattern_layout(cfg)[1]):
-        x, _, a = apply_block(p_rest, cfg, kind, x, positions, None)
-        aux_total = aux_total + a
-    return x, aux_total
+        x, _, s = apply_block(p_rest, cfg, kind, x, positions, None)
+        total = add(total, s)
+    return x, total
 
 
 def apply_stack_cached(params, cfg: ModelConfig, x, positions, cache):
@@ -253,7 +263,8 @@ def lm_loss(params, cfg: ModelConfig, batch: Dict, remat_policy="none"):
     x = _embed(params, cfg, batch)
     T = x.shape[1]
     positions = jnp.arange(T)
-    x, aux = apply_stack_train(params, cfg, x, positions, remat_policy)
+    x, stats = apply_stack_train(params, cfg, x, positions, remat_policy)
+    aux = stats["aux"]
     logits = _logits(params, cfg, x).astype(jnp.float32)
     labels = batch["labels"]
     mask = (labels >= 0).astype(jnp.float32)
@@ -263,9 +274,12 @@ def lm_loss(params, cfg: ModelConfig, batch: Dict, remat_policy="none"):
     ce = (lse - gold) * mask
     denom = jnp.maximum(mask.sum(), 1.0)
     loss = ce.sum() / denom
-    zloss = 1e-4 * ((lse * mask) ** 2).sum() / denom
-    total = loss + zloss + 1e-2 * aux
-    return total, {"ce": loss, "zloss": zloss, "aux": aux, "tokens": denom}
+    zloss = Z_LOSS * ((lse * mask) ** 2).sum() / denom
+    total = loss + zloss + AUX_LOSS * aux
+    metrics = {"ce": loss, "zloss": zloss, "aux": aux, "tokens": denom}
+    if "expert_rows" in stats:
+        metrics["expert_rows"] = stats["expert_rows"]
+    return total, metrics
 
 
 def lm_prefill(params, cfg: ModelConfig, batch: Dict, cache):

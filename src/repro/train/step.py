@@ -29,6 +29,52 @@ from repro.models.zoo import Model
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update, opt_state_axes
 
 
+class TrainStep:
+    """The jitted train step, and running totals of the counters its metrics
+    carry (``COUNTERS``; ``expert_rows``: the pairs routed to the experts
+    this chip holds, summed over layers).
+
+    A step's counters stay on the device: no call waits for the step.  The
+    last ``KEEP`` steps' values are kept one by one, the older ones folded
+    into a running total on the device.
+    """
+
+    COUNTERS = ("expert_rows",)
+    KEEP = 1024
+
+    def __init__(self, jitted) -> None:
+        self.jitted = jitted
+        self.lower = jitted.lower
+        self.steps = 0
+        self._older: Dict[str, jax.Array] = {}
+        self._recent: Dict[str, list] = {}
+
+    def __call__(self, state, batch):
+        state, metrics = self.jitted(state, batch)
+        self.steps += 1
+        for name in self.COUNTERS:
+            if name in metrics:
+                recent = self._recent.setdefault(name, [])
+                recent.append(metrics[name])
+                if len(recent) >= 2 * self.KEEP:
+                    fold = jnp.sum(jnp.stack(recent[:-self.KEEP]))
+                    self._older[name] = self._older.get(name, 0) + fold
+                    del recent[:-self.KEEP]
+        return state, metrics
+
+    def total(self, name: str, last: Optional[int] = None) -> Optional[int]:
+        """The counter summed over every step so far, or over the ``last``
+        steps (at most ``KEEP``); None if no step has counted it."""
+        recent = self._recent.get(name)
+        if not recent:
+            return None
+        if last is not None:
+            if last > len(recent):
+                raise ValueError(f"only the last {len(recent)} steps are kept, {last} asked for")
+            return int(sum(int(v) for v in recent[len(recent) - last:]))
+        return int(self._older.get(name, 0)) + int(sum(int(v) for v in recent))
+
+
 class TrainStepBuilder:
     def __init__(
         self,
@@ -162,12 +208,12 @@ class TrainStepBuilder:
             gathered_sh = PT.shardings_for_tree(
                 self.mesh, tp_rules, abstract_params, axes_tree)
             grad_sh = state_sh["params"]
-        return jax.jit(
+        return TrainStep(jax.jit(
             self.train_step_fn(gathered_sh=gathered_sh, grad_sh=grad_sh),
             in_shardings=(state_sh, batch_sh),
             out_shardings=(state_sh, None),
             donate_argnums=(0,),
-        )
+        ))
 
     # -------------------------------------------------------------- serve steps
     def prefill_step_fn(self):

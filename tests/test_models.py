@@ -140,3 +140,38 @@ def test_long_500k_applicability_flags():
     cell = SHAPES["long_500k"]
     ok, why = applicable(get_config("qwen3-32b"), cell)
     assert not ok and "quadratic" in why
+
+
+def _old_head_rmsnorm(x, scale):
+    """The per-head QK-norm as qwen3's path computed it before the norm eps
+    and the full-width QK-norm became configurable."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
+    return (xf * scale).astype(x.dtype)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-32b", "olmo-1b"])
+def test_attention_and_norms_bit_identical_to_their_fixed_eps_form(arch):
+    """qwen3's per-head QK-norm and RMSNorm and OLMo's LayerNorm keep their
+    1e-6 and 1e-5 when the configuration states no eps: bit for bit."""
+    from repro.models import layers as Ly
+    cfg = get_config(arch).reduced(dtype="bfloat16")
+    model = build_model(cfg)
+    params, _ = model.init(RNG)
+    blk = jax.tree.map(lambda a: a[0], params["groups"][0])
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 8, cfg.d_model)).astype(jnp.bfloat16)
+    q, k, _ = Ly._project_qkv(blk["mixer"], cfg, x, jnp.arange(8))
+    wq = jnp.einsum("btd,dhk->bhtk", x, blk["mixer"]["wq"])
+    wk = jnp.einsum("btd,dhk->bhtk", x, blk["mixer"]["wk"])
+    if cfg.qk_norm:
+        wq = _old_head_rmsnorm(wq, blk["mixer"]["q_scale"])
+        wk = _old_head_rmsnorm(wk, blk["mixer"]["k_scale"])
+    np.testing.assert_array_equal(q, Ly.rope(wq, jnp.arange(8), cfg.rope_theta))
+    np.testing.assert_array_equal(k, Ly.rope(wk, jnp.arange(8), cfg.rope_theta))
+    xf = x.astype(jnp.float32)
+    if cfg.norm_kind == "rmsnorm":
+        old = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6) * blk["norm1"]["scale"]
+    else:
+        mean = jnp.mean(xf, -1, keepdims=True)
+        old = (xf - mean) * jax.lax.rsqrt(jnp.mean((xf - mean) ** 2, -1, keepdims=True) + 1e-5)
+    np.testing.assert_array_equal(Ly.apply_norm(blk["norm1"], cfg, x), old.astype(x.dtype))

@@ -9,6 +9,7 @@ time may load the TPU library.
 """
 
 import os
+import re
 
 import pytest
 
@@ -64,3 +65,24 @@ def _compiled_text(fn, *args) -> str:
 def test_kernel_compiles_for_v5e(kernel, shape, one_chip, no_compile_cache):
     x = jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
     assert "tpu_custom_call" in _compiled_text(jax.jit(kernel), x)
+
+
+def test_expert_gmm_compiles_for_v5e(one_chip, no_compile_cache):
+    """The expert layer's grouped products, forward and both gradients, at
+    OLMoE's widths: 4,096 tokens x top-8 pairs over the 8 experts held."""
+    from repro.kernels.grouped_matmul import grouped_matmul_pallas
+
+    def step(lhs, rhs, sizes):
+        return jax.grad(lambda a, b: jnp.sum(grouped_matmul_pallas(a, b, sizes)),
+                        argnums=(0, 1))(lhs, rhs)
+
+    lhs = jax.ShapeDtypeStruct((32768, 2048), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((8, 2048, 1024), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(jax.jit(step), lhs, rhs, sizes)
+    assert "tpu_custom_call" in text
+    # the kernels' device ops carry the names a trace selects them by
+    # (``bench/metrics/expert_gmm_roofline.py``)
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = \S+ custom-call\(", text, re.M)
+    kernel = re.compile(r"(^|_)expert_t?gmm(___)?(\.\d+)?$")
+    assert {"gmm", "tgmm"} == {re.search(r"t?gmm", o).group() for o in ops if kernel.search(o)}
