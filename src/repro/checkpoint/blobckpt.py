@@ -36,6 +36,9 @@ a dirty page is pulled once with ``jax.device_get`` (a clean leaf never
 leaves the device) and all dirty runs of a save ride one batched
 ``write_many`` (a real multi-host deployment would hand each host its
 own leaf shards; the interface is per-leaf so that change is local).
+A wholly dirty leaf's host copy is handed over to the store, which
+keeps it as the pages instead of copying each one out of it (a client
+that dedups copies every page).
 Restored leaves come back as numpy, for the caller to place with its
 shardings.
 """
@@ -67,6 +70,7 @@ class CheckpointStats:
     pages_written: int
     d2h_bytes: int = 0   # bytes pulled from the device (the dirty leaves)
     pack_copy_bytes: int = 0  # bytes of runs copied to be zero-padded
+    store_copy_bytes: int = 0  # bytes of runs the store copied, not given
 
     @property
     def sharing_fraction(self) -> float:
@@ -168,6 +172,7 @@ class BlobCheckpointer:
         pages_written = 0
         d2h_bytes = 0
         pack_copy_bytes = 0
+        store_copy_bytes = 0
         pages_total = (total - self.header_bytes) // psz
         manifest_leaves = []
         new_digests: Dict[str, np.ndarray] = {}
@@ -181,6 +186,10 @@ class BlobCheckpointer:
         # write_many as the dedup-handshake input — the content-hash
         # index matches on exactly these digests, nothing hashes twice
         dirty_digests: List[List[Tuple[int, int]]] = []
+        # per run, whether its buffer is handed over to the store (kept
+        # as its pages, never copied) or copied page by page there
+        dirty_given: List[bool] = []
+        hand_over = not self.client.dedup_default
         for path, leaf in leaves:
             off, nbytes = layout[path]
             with span("ckpt.digest"):
@@ -209,11 +218,18 @@ class BlobCheckpointer:
                 # write contiguous dirty page runs of full pages: page-aligned
                 # writes are BlobSeer's fast path (no boundary merging) and
                 # keep blob growth contiguous.  A run goes out as a read-only
-                # view of the D2H buffer (the store copies each page out of
-                # it once); only a run whose last page passes the leaf's end
-                # is copied here, to be zero-padded
+                # view of the D2H buffer; only a run whose last page passes
+                # the leaf's end is copied here, to be zero-padded.  The
+                # store keeps a run as its pages, uncopied, where the run
+                # is that fresh padded copy or the leaf is wholly dirty: a
+                # jax.Array's host copy never changes, and the stored runs
+                # then cover the whole buffer, so a kept buffer holds no
+                # bytes but this leaf's.  Else, and always under dedup
+                # (see write_many), the store copies each page out.
                 n_pages = dg.shape[0]
                 view = memoryview(raw).toreadonly()
+                whole = (hand_over and isinstance(leaf, jax.Array)
+                         and bool(dirty.all()))
                 i = 0
                 while i < n_pages:
                     if not dirty[i]:
@@ -225,10 +241,15 @@ class BlobCheckpointer:
                     lo, hi = i * psz, j * psz
                     if hi <= raw.size:
                         chunk = view[lo:hi]
+                        given = whole
                     else:
                         chunk = raw[lo:].tobytes() + b"\0" * (hi - raw.size)
                         pack_copy_bytes += hi - lo
+                        given = hand_over
+                    if not given:
+                        store_copy_bytes += hi - lo
                     dirty_writes.append((chunk, off + lo))
+                    dirty_given.append(given)
                     dirty_digests.append(
                         [(int(dg[k, 0]), int(dg[k, 1])) for k in range(i, j)])
                     written_bytes += len(chunk)
@@ -237,7 +258,7 @@ class BlobCheckpointer:
 
         if dirty_writes:
             self.client.write_many(self.blob_id, dirty_writes,
-                                   digests=dirty_digests)
+                                   digests=dirty_digests, given=dirty_given)
 
         with span("ckpt.commit"):
             manifest = {
@@ -286,7 +307,7 @@ class BlobCheckpointer:
             version=vc, step=step, total_bytes=total,
             written_bytes=written_bytes, pages_total=pages_total,
             pages_written=pages_written, d2h_bytes=d2h_bytes,
-            pack_copy_bytes=pack_copy_bytes,
+            pack_copy_bytes=pack_copy_bytes, store_copy_bytes=store_copy_bytes,
         )
 
     # ---------------------------------------------------------------- restore

@@ -35,6 +35,8 @@ import itertools
 import threading
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from repro.core import segment_tree as st
 from repro.core.cache import NodeCache
 from repro.core.dedup_index import DedupIndex
@@ -464,7 +466,7 @@ class BlobClient:
         in buffer order.
 
         ``dedup``/``digests`` and the buffers' types: see
-        :meth:`write_many`.
+        :meth:`write_many`.  Every stored page is a copy.
         """
         return self._update_many(blob_id, [(buf, None) for buf in bufs],
                                  digests=digests, dedup=dedup)
@@ -473,7 +475,8 @@ class BlobClient:
                    items: Sequence[Tuple[bytes, int]],
                    *,
                    digests: Optional[Sequence[Sequence[Tuple[int, int]]]] = None,
-                   dedup: Optional[bool] = None) -> List[int]:
+                   dedup: Optional[bool] = None,
+                   given: Optional[Sequence[bool]] = None) -> List[int]:
         """WRITE a batch of ``(buf, offset)`` updates in one pass.
 
         One snapshot version per item, assigned and published in list
@@ -484,10 +487,21 @@ class BlobClient:
         extend the blob and item *k+1* may write into the extension.
 
         A ``buf`` may be ``bytes`` or any contiguous 1-D byte buffer (a
-        ``bytearray``, a ``memoryview`` of format ``B``).  Every stored
-        page is an independent ``bytes`` copy (a slice of a ``bytes``
-        buffer, copied once out of any other), so the caller may reuse
-        or change the buffer once the call returns.
+        ``bytearray``, a ``memoryview`` of format ``B``).  By default
+        every stored full page is an independent ``bytes`` copy (a slice
+        of a ``bytes`` buffer, copied once out of any other), so the
+        caller may reuse or change the buffer once the call returns.
+
+        ``given`` (default: none) marks, per item, a buffer the caller
+        hands over to the store: it will never change again.  That
+        item's full pages are kept as read-only numpy views of the
+        buffer, one row of it per page, and nothing is copied; the store
+        holds the buffer until the last of those pages is deleted.
+        Boundary pages are merged into fresh ``bytes`` either way.
+        Versions, page ids and snapshots are those of the copy.  Under
+        ``dedup`` every page is copied, ``given`` or not: a later write
+        that matches one kept row would hold its whole buffer alive, as
+        would a buffer of which most pages matched older ones.
 
         ``dedup`` (default: the client's ``dedup`` constructor flag)
         enables the two-phase dedup handshake on the burst's full
@@ -502,12 +516,13 @@ class BlobClient:
         ``hostdigest.host_page_digest`` fills in.
         """
         return self._update_many(blob_id, [(buf, off) for buf, off in items],
-                                 digests=digests, dedup=dedup)
+                                 digests=digests, dedup=dedup, given=given)
 
     def _update_many(self, blob_id: str,
                      items: Sequence[Tuple[bytes, Optional[int]]],
                      digests: Optional[Sequence[Sequence[Tuple[int, int]]]] = None,
-                     dedup: Optional[bool] = None) -> List[int]:
+                     dedup: Optional[bool] = None,
+                     given: Optional[Sequence[bool]] = None) -> List[int]:
         items = list(items)
         if not items:
             return []
@@ -521,6 +536,10 @@ class BlobClient:
             and self.dedup_index is not None
         if digests is not None and len(digests) != len(items):
             raise ValueError("digests must align with items")
+        if given is None:
+            given = [False] * len(items)
+        elif len(given) != len(items):
+            raise ValueError("given must align with items")
         # Page-ids this burst acquired from / registered with the dedup
         # index; released if a re-stripe abandons the optimistic pages.
         acquired: List[str] = []
@@ -539,7 +558,9 @@ class BlobClient:
                 if is_append:
                     cursor += len(buf)
                 p0_pre, _ = pages_spanned(p_off, len(buf), psize)
-                plans.append((idx, self._plan_full_pages(buf, p_off, psize, p0_pre)))
+                plans.append((idx, self._plan_full_pages(
+                    buf, p_off, psize, p0_pre,
+                    keep=given[idx] and not use_dedup)))
             barrier = self._store_planned(
                 plans, stored, psize=psize, digests=digests,
                 use_dedup=use_dedup, acquired=acquired, blob_id=blob_id)
@@ -639,20 +660,31 @@ class BlobClient:
 
     # ------------------------------------------------------- update internals
     def _plan_full_pages(
-        self, buf: bytes, off: int, psize: int, p0: int,
+        self, buf: bytes, off: int, psize: int, p0: int, keep: bool = False,
     ) -> List[Tuple[int, bytes]]:
         """``(rel_page, payload)`` for every page fully covered by the
         byte range ``[off, off+len(buf))`` (boundary pages are phase 3's
         job).  ``p0`` is the update's first touched page.  A payload is a
         slice of a ``bytes`` buffer (``bytes()`` of it is the slice
         itself), else one ``bytes`` copy out of the buffer: stored pages
-        never alias the caller's memory."""
+        never alias the caller's memory.  With ``keep`` (a buffer handed
+        over) each payload is instead a read-only row view of the buffer:
+        no copy, and an ndarray is no object the interpreter's cycle
+        collector tracks."""
         full_lo = -(-off // psize)                 # first fully covered page
         full_hi = (off + len(buf)) // psize        # one past last fully covered
-        return [
-            (k - p0, bytes(buf[k * psize - off:(k + 1) * psize - off]))
-            for k in range(full_lo, full_hi)
-        ]
+        if not keep:
+            return [
+                (k - p0, bytes(buf[k * psize - off:(k + 1) * psize - off]))
+                for k in range(full_lo, full_hi)
+            ]
+        if full_hi <= full_lo:
+            return []
+        arr = np.frombuffer(buf, dtype=np.uint8)
+        arr.flags.writeable = False
+        a = full_lo * psize - off
+        rows = arr[a:a + (full_hi - full_lo) * psize].reshape(-1, psize)
+        return list(zip(range(full_lo - p0, full_hi - p0), rows))
 
     def _store_planned(
         self,

@@ -60,6 +60,7 @@ from repro.core.transport import (
     Wire,
 )
 from repro.store.memory import MemoryPageStore
+from repro.store.page import page_view
 
 # Outstanding-bytes estimate for a whole-page request (length=None) when
 # spreading a fetch wave across replicas; only relative magnitudes
@@ -87,7 +88,7 @@ def _digest(payload: bytes) -> int:
     control-plane processes without accelerators.
     """
     h = 0xCBF29CE484222325
-    for b in payload:
+    for b in page_view(payload):
         h ^= b
         h = (h * 0x100000001B3) & 0xFFFFFFFFFFFFFFFF
     return h
@@ -167,7 +168,7 @@ class DataProvider:
                 raise PageIntegrityError(page_id)
         if length is None:
             length = len(payload) - off
-        return payload[off : off + length]
+        return page_view(payload)[off : off + length]
 
     def get_page(
         self,

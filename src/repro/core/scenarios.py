@@ -47,6 +47,7 @@ from repro.core.service import BlobSeerService
 from repro.core.sim import Simulator
 from repro.core.transport import EndpointDown, Wire
 from repro.core.version_manager import RetiredVersion
+from repro.store.page import page_view
 
 
 @dataclass
@@ -1219,7 +1220,7 @@ def apply_failure_target(svc: BlobSeerService, state: Dict[str, object],
         victims = sorted(prov.store.iter_pids())
         if victims:
             vic = victims[0]
-            payload = prov.store.get(vic)
+            payload = page_view(prov.store.get(vic))
             # mutate the raw store, NOT through delete_pages /
             # put_pages — silent corruption leaves bookkeeping
             # (digests, timestamps) untouched

@@ -10,6 +10,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, Iterator, Optional
 
+from repro.store.page import page_view
+
 
 class MemoryPageStore:
     def __init__(self) -> None:
@@ -21,7 +23,8 @@ class MemoryPageStore:
             # Page ids are globally unique; a duplicate put is a replica
             # re-send and must carry identical content.
             prev = self._pages.get(pid)
-            if prev is not None and prev is not payload and prev != payload:
+            if (prev is not None and prev is not payload
+                    and page_view(prev) != page_view(payload)):
                 raise ValueError(f"page {pid} re-stored with different content")
             self._pages[pid] = payload
 

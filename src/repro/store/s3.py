@@ -23,6 +23,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, Iterator, Optional
 
+from repro.store.page import page_view
+
 
 class S3PageStore:
     def __init__(self, bucket: str, prefix: str = "pages") -> None:
@@ -47,7 +49,7 @@ class S3PageStore:
             # object stores are last-writer-wins; immutability comes from
             # pid uniqueness upstream, so a re-put must match
             prev = self._objects.get(key)
-            if prev is not None and prev != payload:
+            if prev is not None and page_view(prev) != page_view(payload):
                 raise ValueError(
                     f"page {pid} re-stored with different content")
             self._objects[key] = payload

@@ -1,6 +1,8 @@
 """BlobSeer checkpointing: incremental COW, atomic publish, branch, resume."""
 
+import gc
 import threading
+import weakref
 
 import numpy as np
 import pytest
@@ -10,7 +12,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import BlobCheckpointer
 from repro.checkpoint.blobckpt import header_pages_for
-from repro.core import BlobSeerService
+from repro.core import BlobSeerService, collect_garbage
 from repro.data import ByteTokenizer, CorpusWriter, ShardedReader
 
 
@@ -95,6 +97,168 @@ def test_pack_copies_only_the_padded_run(ckpt_env):
     state["tail"] = state["tail"].at[69].set(-1.0)
     save_and_check(3, 1, 256)           # its last page is padded
     assert ck.save(state, step=4).pack_copy_bytes == 0
+
+
+def _kept_pages(svc):
+    """Stored pages kept as views of a handed-over buffer (not ``bytes``)."""
+    pages = [p.store.get(q) for p in svc.pm.all_providers()
+             for q in p.store.iter_pids()]
+    return [p for p in pages if type(p) is not bytes]
+
+
+def _owner(page):
+    """The array at the root of a kept page's views: the D2H buffer."""
+    obj = page
+    while True:
+        nxt = obj.obj if isinstance(obj, memoryview) else obj.base
+        if not isinstance(nxt, (np.ndarray, memoryview)):
+            return obj
+        obj = nxt
+
+
+def _two_leaves(seed):
+    k0, k1 = jax.random.split(jax.random.PRNGKey(seed))
+    return {"whole": jax.random.normal(k0, (256,)),     # 4 pages exactly
+            "tail": jax.random.normal(k1, (70,))}       # 280 B: 2 pages
+
+
+def test_full_save_hands_over_every_fully_dirty_leaf(ckpt_env):
+    """A full save's runs are all kept by the store uncopied: the whole
+    leaf's pages are rows of its D2H buffer, the padded tail's of the
+    pack's own copy."""
+    svc, c = ckpt_env
+    ck = BlobCheckpointer(c, psize=256, header_pages=8)
+    state = _two_leaves(5)
+    stats = ck.save(state, step=0)
+    assert (stats.pages_written, stats.pack_copy_bytes,
+            stats.store_copy_bytes) == (6, 2 * 256, 0)
+    kept = _kept_pages(svc)
+    assert len(kept) == 6
+    whole = [p for p in kept
+             if np.shares_memory(p, jax.device_get(state["whole"]))]
+    assert len(whole) == 4
+    assert b"".join(sorted(p.tobytes() for p in whole)) == b"".join(
+        sorted(np.asarray(state["whole"]).view(np.uint8)
+               .reshape(4, 256)[k].tobytes() for k in range(4)))
+
+
+def test_sparse_save_copies_partly_dirty_runs(ckpt_env):
+    """A leaf with clean pages keeps the store's copy: no stored page
+    aliases its buffer.  A padded tail run is the pack's own copy and
+    is kept."""
+    svc, c = ckpt_env
+    ck = BlobCheckpointer(c, psize=256, header_pages=8)
+    state = _two_leaves(6)
+    like = jax.eval_shape(lambda: state)
+    ck.save(state, step=0)
+    n_kept = len(_kept_pages(svc))
+    state["whole"] = state["whole"].at[130].set(-1.0)
+    stats = ck.save(state, step=1)
+    assert (stats.pages_written, stats.store_copy_bytes) == (1, 256)
+    host = jax.device_get(state["whole"])
+    assert not any(np.shares_memory(p, host) for p in _kept_pages(svc))
+    assert len(_kept_pages(svc)) == n_kept
+    state["tail"] = state["tail"].at[69].set(-1.0)
+    stats = ck.save(state, step=2)
+    assert (stats.pages_written, stats.pack_copy_bytes,
+            stats.store_copy_bytes) == (1, 256, 0)
+    assert len(_kept_pages(svc)) == n_kept + 1
+    got = ck.restore(like, version=stats.version)
+    for key, leaf in state.items():
+        assert got[key].tobytes() == np.asarray(leaf).tobytes()
+
+
+def test_numpy_leaf_is_copied_not_kept(ckpt_env):
+    """A numpy leaf is the caller's own mutable buffer, not a jax.Array's
+    fixed host copy: the store copies it, and a later in-place change
+    leaves the checkpoint as saved."""
+    svc, c = ckpt_env
+    ck = BlobCheckpointer(c, psize=256, header_pages=8)
+    w = np.arange(128, dtype=np.float32)                 # 2 pages
+    stats = ck.save({"w": w}, step=0)
+    assert stats.store_copy_bytes == 512 and not _kept_pages(svc)
+    w[:] = -1.0
+    got = ck.restore({"w": jax.ShapeDtypeStruct((128,), jnp.float32)},
+                     version=stats.version)
+    assert got["w"].tobytes() == np.arange(128, dtype=np.float32).tobytes()
+
+
+def test_restore_across_full_and_sparse_saves_with_gc(ckpt_env):
+    """Full, sparse, full, sparse saves with a retiring GC round after
+    each: the newest checkpoint restores bit for bit every time."""
+    svc, c = ckpt_env
+    ck = BlobCheckpointer(c, psize=256, header_pages=8)
+    c.set_retention(ck.blob_id, keep_last=1)
+    state = _two_leaves(7)
+    like = jax.eval_shape(lambda: state)
+    edits = [None, ("whole", 3), None, ("tail", 69), ("whole", 255)]
+    for step, edit in enumerate(edits):
+        if step and edit is None:
+            state = jax.tree.map(lambda x: x * 1.5 + 1.0, state)  # full
+        elif edit is not None:
+            key, i = edit
+            state[key] = state[key].at[i].set(float(-step))
+        stats = ck.save(state, step=step)
+        assert (stats.store_copy_bytes == 0) == (edit is None or edit[0] == "tail")
+        collect_garbage(svc, orphan_grace=None)
+        got = ck.restore(like)
+        for key, leaf in state.items():
+            assert got[key].tobytes() == np.asarray(leaf).tobytes()
+
+
+def test_gc_frees_handed_over_buffers(ckpt_env):
+    """The store holds a handed-over D2H buffer while its checkpoint is
+    live, and lets it go once GC retires that checkpoint."""
+    svc, c = ckpt_env
+    ck = BlobCheckpointer(c, psize=256, header_pages=8)
+    c.set_retention(ck.blob_id, keep_last=1)
+    state = {"w": jax.random.normal(jax.random.PRNGKey(8), (512,))}
+    ck.save(state, step=0)
+    kept = _kept_pages(svc)
+    owners = {id(_owner(p)): _owner(p) for p in kept}
+    assert len(kept) == 8 and len(owners) == 1
+    (owner,) = [weakref.ref(o) for o in owners.values()]
+    del kept, owners
+    state = {"w": state["w"] + 1.0}
+    gc.collect()
+    assert owner() is not None                  # the live checkpoint's pages
+    ck.save(state, step=1)
+    collect_garbage(svc, orphan_grace=None)
+    gc.collect()
+    assert owner() is None
+    got = ck.restore(jax.eval_shape(lambda: state))
+    assert got["w"].tobytes() == np.asarray(state["w"]).tobytes()
+
+
+@pytest.mark.parametrize("dedup", [False, True], ids=["plain", "dedup"])
+def test_dedup_save_copies_and_holds_no_buffer(dedup, monkeypatch):
+    """Under dedup the store copies every run, so no stored page keeps a
+    D2H buffer alive (a later hit on one row would hold all of it); with
+    dedup off the live checkpoint holds its wholly kept leaf's buffer."""
+    pulled = []
+    device_get = jax.device_get
+
+    def recording_device_get(x):
+        out = device_get(x)
+        pulled.append(weakref.ref(out))
+        return out
+
+    monkeypatch.setattr(jax, "device_get", recording_device_get)
+    svc = BlobSeerService(n_providers=6, n_meta_shards=4, dedup=dedup)
+    ck = BlobCheckpointer(svc.client(), psize=256, header_pages=8)
+    state = _two_leaves(9)
+    like = jax.eval_shape(lambda: state)
+    want = {k: np.asarray(v).tobytes() for k, v in state.items()}
+    stats = ck.save(state, step=0)
+    assert stats.pages_written == 6
+    assert stats.store_copy_bytes == (6 * 256 if dedup else 0)
+    assert len(_kept_pages(svc)) == (0 if dedup else 6)
+    del state
+    gc.collect()
+    # leaves in path order: "tail" (its one run is the padded copy), "whole"
+    assert [r() is not None for r in pulled] == [False, not dedup]
+    got = ck.restore(like, version=stats.version)
+    assert {k: v.tobytes() for k, v in got.items()} == want
 
 
 def test_old_checkpoints_remain_readable(ckpt_env):

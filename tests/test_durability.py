@@ -186,6 +186,33 @@ def test_scrub_detects_and_repairs_corruption_in_place():
     assert svc.scrub()["damaged_pages"] == 0
 
 
+def test_scrub_repairs_a_corrupt_copy_of_a_kept_page():
+    """Pages kept as views of a handed-over buffer (``given``) are
+    digested, corrupted by the chaos target, scrubbed and repaired like
+    ``bytes`` pages, and read back unchanged."""
+    import numpy as np
+
+    from repro.core.scenarios import apply_failure_target
+
+    svc = BlobSeerService(n_providers=4, n_meta_shards=2,
+                          data_replication=2, verify_digests=True)
+    c = svc.client("w")
+    bid = c.create(psize=1024)
+    data = bytes(range(256)) * 16
+    v = c.write_many(bid, [(np.frombuffer(data, np.uint8), 0)],
+                     given=[True])[0]
+    prov = svc.pm.get("prov-0001")
+    assert all(type(prov.store.get(q)) is np.ndarray
+               for q in prov.store.iter_pids())
+    assert svc.scrub()["damaged_pages"] == 0
+    apply_failure_target(svc, {}, "corrupt:prov-0001")
+    assert c.read(bid, v, 0, len(data)) == data   # fails over meanwhile
+    stats = svc.scrub()
+    assert stats["corrupt_copies"] == 1 and stats["repaired_pages"] == 1
+    assert svc.scrub()["damaged_pages"] == 0
+    assert svc.client("r").read(bid, v, 0, len(data)) == data
+
+
 def test_scrub_budget_defers_and_converges():
     svc = BlobSeerService(n_providers=6, n_meta_shards=2,
                           data_replication=2, verify_digests=True)

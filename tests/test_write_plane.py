@@ -18,7 +18,9 @@ Covers the PR-5 contracts:
 
 from __future__ import annotations
 
+import gc
 import threading
+import weakref
 
 import numpy as np
 import pytest
@@ -270,6 +272,83 @@ def test_batched_verbs_take_byte_views(verb):
         for b, off in zip(_VIEW_BUFS, _VIEW_OFFSETS):
             ref[off:off + len(b)] = b
         assert snaps_v[-1] == bytes(ref)
+
+
+# Full pages of each _VIEW_BUFS item written at _VIEW_OFFSETS, psize 16
+_FULL_PAGES = [1, 0, 2, 3]
+_GIVEN = [True, False, True, True]      # item 1 (no full page) mixed in too
+
+
+@pytest.mark.parametrize("dedup", [False, True], ids=["plain", "dedup"])
+@pytest.mark.parametrize("given", [_GIVEN, [False, True, False, True]],
+                         ids=["mostly-given", "alternate"])
+def test_given_buffers_are_kept_uncopied(given, dedup):
+    """A handed-over item's full pages are read-only rows of its buffer
+    (no copy), which the store then holds; the other items' pages are
+    ``bytes`` copies, as is every page under dedup, which holds no
+    buffer.  Versions and every snapshot are those of the all-``bytes``
+    batch."""
+    vs_b, snaps_b, _ = _batch_through("write_many", as_views=False)
+    svc = BlobSeerService(n_providers=4, n_meta_shards=2, dedup=dedup)
+    c = svc.client()
+    bid = c.create(psize=16)
+    c.write(bid, b"x" * 70, 0)
+    sources = [np.frombuffer(b, np.uint8).copy() for b in _VIEW_BUFS]
+    bufs = [memoryview(a).toreadonly() if g else memoryview(a)
+            for a, g in zip(sources, given)]
+    vs = c.write_many(bid, list(zip(bufs, _VIEW_OFFSETS)), given=given)
+    kept = [g and not dedup for g in given]
+    for a, k in zip(sources, kept):
+        if not k:
+            a.fill(0xEE)                  # only kept buffers must not change
+    assert vs == vs_b
+    assert [c.read(bid, v, 0, c.get_size(bid, v)) for v in vs] == snaps_b
+    pages = [p.store.get(pid) for p in svc.pm.all_providers()
+             for pid in p.store.iter_pids()]
+    views = [p for p in pages if type(p) is not bytes]
+    assert all(isinstance(p, np.ndarray) and not p.flags.writeable
+               and len(p) == 16 for p in views)
+    for src, k, n in zip(sources, kept, _FULL_PAGES):
+        assert sum(np.shares_memory(p, src) for p in views) == (n if k else 0)
+    assert len(views) == sum(n for k, n in zip(kept, _FULL_PAGES) if k)
+    if views:
+        # a kept page reads back through the provider API as its bytes
+        prov = next(p for p in svc.pm.all_providers()
+                    if any(type(p.store.get(q)) is not bytes
+                           for q in p.store.iter_pids()))
+        pid = next(q for q in prov.store.iter_pids()
+                   if type(prov.store.get(q)) is not bytes)
+        assert prov.get_page(pid) == prov.store.get(pid).tobytes()
+        assert prov.get_page(pid, 3, 5) == prov.store.get(pid)[3:8].tobytes()
+    # the store holds a buffer exactly while it keeps rows of it
+    refs = [weakref.ref(a) for a in sources]
+    del sources, bufs, pages, views, a, src    # and the loops' last items
+    gc.collect()
+    assert [r() is not None for r in refs] == [
+        k and n > 0 for k, n in zip(kept, _FULL_PAGES)]
+
+
+def test_memory_store_compares_kept_pages_by_content():
+    """A re-sent page id is accepted with equal bytes, whatever the
+    payload's type, and refused with different bytes."""
+    from repro.store.memory import MemoryPageStore
+
+    store = MemoryPageStore()
+    store.put("p", np.frombuffer(b"a" * 16, np.uint8))
+    store.put("p", b"a" * 16)
+    store.put("p", np.frombuffer(b"a" * 16, np.uint8))
+    with pytest.raises(ValueError):
+        store.put("p", np.frombuffer(b"b" * 16, np.uint8))
+    with pytest.raises(ValueError):
+        store.put("p", b"b" * 16)
+
+
+def test_given_must_align_with_items():
+    svc = BlobSeerService(n_providers=2, n_meta_shards=2)
+    c = svc.client()
+    bid = c.create(psize=16)
+    with pytest.raises(ValueError):
+        c.write_many(bid, [(b"a" * 16, 0), (b"b" * 16, 16)], given=[True])
 
 
 def test_mixed_append_write_batch_rejected():
