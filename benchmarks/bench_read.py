@@ -23,7 +23,7 @@ def run(rep: Reporter, *, total_mb: int = 128, chunk_mb: int = 8) -> None:
     # locally and fake the provider contention this figure measures.
     # The cached regime has its own benchmark (bench_cache).
     svc = BlobSeerService(n_providers=n_nodes - 2, n_meta_shards=n_nodes - 2,
-                          placement="two_choice", page_cache_bytes=0)
+                          page_cache_bytes=0)
     writer = svc.client("writer")
     bid = writer.create(psize=64 * 1024)
     payload = b"\xcd" * (4 * 1024 * 1024)

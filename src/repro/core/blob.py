@@ -52,11 +52,6 @@ from repro.core.version_manager import (
 from repro.kernels.hostdigest import host_page_digest
 from repro.spans import span
 
-# Backwards-compatible alias: the node cache grew up and moved to
-# repro.core.cache (shared with the page cache and the accounting
-# layer); existing imports keep working.
-_NodeCache = NodeCache
-
 _client_ids = itertools.count()
 _client_ids_lock = threading.Lock()
 
@@ -204,7 +199,6 @@ class BlobClient:
         pm: ProviderManager,
         wire: Wire,
         name: Optional[str] = None,
-        io_workers: int = 0,
         prefetch_pages: int = 0,
         dedup_index: Optional["DedupIndex"] = None,
         dedup: bool = False,
@@ -214,13 +208,6 @@ class BlobClient:
         (0 = off).  Sequential readers hide the next read's data-plane
         latency this way; the descriptors come from widening the same
         segment-tree descent the read already pays for.
-
-        ``io_workers`` is accepted for backward compatibility and is a
-        no-op: the thread-pool fan-out it once enabled is subsumed by
-        the batched write plane (``ProviderManager.store_pages`` groups
-        all page stores per endpoint into single round trips and
-        pipelines them under a virtual clock), which models the paper's
-        'in parallel' loops without real threads.
 
         ``dedup_index``: the deployment's content-hash page index (see
         :mod:`repro.core.dedup_index`); ``dedup`` sets this client's
@@ -237,7 +224,6 @@ class BlobClient:
             with _client_ids_lock:
                 name = f"client-{next(_client_ids):04d}"
         self.name = name
-        del io_workers  # no-op, see docstring
         self._lineage_cache: Dict[str, Tuple[Tuple[str, int], ...]] = {}
         # per-client request sequence: idempotency keys for assign verbs
         # (a re-driven request after a VM leader failover returns its

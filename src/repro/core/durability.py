@@ -443,12 +443,8 @@ def lifecycle_round(
                         spent + 2 * len(payload) > budget_bytes:
                     stats["deferred"] += 1
                     continue
-                if svc.pm.ring is not None:
-                    owners = svc.pm.ring_owners(
-                        svc.pm.place_key(logical), 1)
-                    target = _provider(svc, owners[0]) if owners else None
-                else:
-                    target = _pick_target(svc, exclude=set())
+                owners = svc.pm.ring_owners(svc.pm.place_key(logical), 1)
+                target = _provider(svc, owners[0]) if owners else None
                 if target is None:
                     stats["deferred"] += 1
                     continue

@@ -270,7 +270,7 @@ def collect_orphans(
     inventory (one batched round trip each) and deletes listed pages
     that are not journaled anywhere and are older than ``grace`` on the
     deployment clock.  The grace window is what makes it safe against
-    in-flight writers between ``store_page`` and ``assign_version``.
+    in-flight writers between ``store_pages`` and ``assign_version``.
 
     With dedup deployed, the inventory also reconciles the content-hash
     index: doomed pages are run through ``orphan_guard`` first — a page

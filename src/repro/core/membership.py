@@ -303,7 +303,7 @@ def join_provider(svc, pid: str, tier: str = "hot") -> List[Move]:
     ring now assigns to it."""
     svc.add_provider(pid, tier=tier)
     svc.pm.announce_join(pid)
-    if tier != "hot" or svc.pm.ring is None:
+    if tier != "hot":
         return []  # cold members take no ring placement, nothing to move
     return build_join_plan(svc, pid)
 

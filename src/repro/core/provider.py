@@ -20,16 +20,17 @@ future work):
 * a shared, byte-budgeted :class:`~repro.core.cache.PageCache` under
   ``fetch_pages`` (immutable pages: hits are always safe; the GC sweep
   invalidates what it deletes),
-* pluggable placement strategies (round-robin, least-loaded,
-  power-of-two-choices).
+* consistent-hash placement: each page's replica group is its ring
+  owner set (:class:`~repro.core.placement.HashRing`), which gives the
+  paper's "even distribution of pages among providers" and lets
+  members join and drain without reshuffling every page.
 """
 
 from __future__ import annotations
 
-import random
 import threading
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.cache import PageCache
 
@@ -308,51 +309,6 @@ class DataProvider:
         return self.store.total_bytes()
 
 
-PlacementStrategy = Callable[["ProviderManager", int], List[DataProvider]]
-
-
-def round_robin(pm: "ProviderManager", n: int) -> List[DataProvider]:
-    alive = pm.placement_pool()
-    if not alive:
-        raise RuntimeError("no alive providers")
-    out = []
-    with pm._rr_lock:
-        for _ in range(n):
-            out.append(alive[pm._rr_next % len(alive)])
-            pm._rr_next += 1
-    return out
-
-def least_loaded(pm: "ProviderManager", n: int) -> List[DataProvider]:
-    alive = pm.placement_pool()
-    if not alive:
-        raise RuntimeError("no alive providers")
-    ordered = sorted(alive, key=lambda p: p.page_count())
-    return [ordered[i % len(ordered)] for i in range(n)]
-
-def two_choice(pm: "ProviderManager", n: int) -> List[DataProvider]:
-    """Power-of-two-choices: near-least-loaded at O(1) coordination cost."""
-    alive = pm.placement_pool()
-    if not alive:
-        raise RuntimeError("no alive providers")
-    rng = pm._rng
-    out = []
-    for _ in range(n):
-        a, b = rng.choice(alive), rng.choice(alive)
-        out.append(a if a.page_count() <= b.page_count() else b)
-    return out
-
-
-STRATEGIES: Dict[str, PlacementStrategy] = {
-    "round_robin": round_robin,
-    "least_loaded": least_loaded,
-    "two_choice": two_choice,
-}
-
-#: The consistent-hash ring strategy is not a :data:`STRATEGIES` entry —
-#: placement is keyed per page (ring state + placement key), not drawn
-#: from a pool sequence, so ``allocate`` handles it directly.
-RING_STRATEGY = "ring"
-
 # "rep:0" = the deployment's default replication factor.
 _DEFAULT_POLICY = ReplicationPolicy(0)
 
@@ -364,24 +320,15 @@ class ProviderManager:
         self,
         wire: Wire,
         *,
-        strategy: str = "round_robin",
         replication: int = 1,
         heartbeat_timeout: float = 5.0,
-        seed: int = 0,
         page_cache: Optional[PageCache] = None,
         dedup_index: Optional["DedupIndex"] = None,
     ) -> None:
         self.wire = wire
-        # "ring": consistent-hash placement (the default deployment
-        # wiring since the elastic-membership PR); the legacy pool
-        # strategies remain selectable for the §5 reproductions that
-        # study them.
-        self.ring: Optional[HashRing] = None
-        if strategy == RING_STRATEGY:
-            self.strategy: Optional[PlacementStrategy] = None
-            self.ring = HashRing()
-        else:
-            self.strategy = STRATEGIES[strategy]
+        # New-page placement: each page goes to its consistent-hash
+        # owners among the hot, live, non-draining members.
+        self.ring = HashRing()
         self.replication = max(1, replication)
         self.heartbeat_timeout = heartbeat_timeout
         # Shared read-path page cache (None or budget 0 = disabled):
@@ -423,9 +370,6 @@ class ProviderManager:
         self._read_tally: Dict[str, int] = {}
         self._served_reads: Dict[str, int] = {}
         self._widened: Dict[str, Tuple[str, ...]] = {}
-        self._rr_next = 0
-        self._rr_lock = threading.Lock()
-        self._rng = random.Random(seed)
         self._ctr_lock = threading.Lock()
         self.read_rounds = 0  # batched provider round trips issued
         self.read_pages = 0   # logical page reads served
@@ -552,15 +496,13 @@ class ProviderManager:
             self._draining.discard(provider.pid)
             # hot endpoints join the placement ring; cold endpoints only
             # ever receive lifecycle demotions, never ring placement
-            if self.ring is not None and getattr(provider, "tier",
-                                                 "hot") == "hot":
+            if getattr(provider, "tier", "hot") == "hot":
                 self.ring.add(provider.pid)
 
     def deregister(self, pid: str) -> None:
         with self._lock:
             self._providers.pop(pid, None)
-            if self.ring is not None:
-                self.ring.remove(pid)
+            self.ring.remove(pid)
 
     def mark_draining(self, pid: str) -> None:
         """Take ``pid`` out of the ring and the placement pool while it
@@ -571,8 +513,7 @@ class ProviderManager:
                            inbound=True, async_peer=True)
         with self._lock:
             self._draining.add(pid)
-            if self.ring is not None:
-                self.ring.remove(pid)
+            self.ring.remove(pid)
 
     def finish_drain(self, pid: str) -> None:
         """Deregister a fully-migrated member.  Departed ids are
@@ -624,7 +565,7 @@ class ProviderManager:
 
         Pages allocated through :meth:`allocate` were keyed by a
         per-blob sequence number recorded at allocation time; anything
-        else (pages predating the ring, restored deployments) falls
+        else (pages allocated without ids, restored deployments) falls
         back to the page id itself — still a pure function of ring
         state within one run, which is all migration planning needs."""
         with self._lock:
@@ -634,8 +575,6 @@ class ProviderManager:
                     extra_exclude: Sequence[str] = ()) -> List[str]:
         """The ``width`` ring owners of placement key ``key`` among the
         currently-eligible (alive, hot, not draining) members."""
-        if self.ring is None:
-            raise RuntimeError("ring placement is not enabled")
         eligible = {p.pid for p in self.placement_pool()} - set(extra_exclude)
         return self.ring.owners(key, width, eligible)
 
@@ -654,8 +593,8 @@ class ProviderManager:
         outright (fewer would co-locate shards and void the fault
         tolerance the parity pays for).
 
-        Under ring placement the group is the page's consistent-hash
-        owner set: ``page_ids`` (when supplied) pin each page's
+        The group is the page's consistent-hash owner set among the
+        eligible members: ``page_ids`` (when supplied) pin each page's
         placement key — a per-blob allocation sequence — so the same
         seed replays to the same layout and a later join/drain can
         recompute exactly which pages it owes or is owed.
@@ -668,31 +607,9 @@ class ProviderManager:
             raise EndpointDown(
                 f"ec placement needs {width} live providers, "
                 f"have {len(alive)}")
-        if self.ring is not None:
-            return self._allocate_ring(n_pages, blob_id, page_ids,
-                                       width, strict)
-        primaries = self.strategy(self, n_pages)
-        groups: List[List[DataProvider]] = []
-        for prim in primaries:
-            group = [prim]
-            if width > 1 and len(alive) > 1:
-                # deterministic walk from the primary for replica diversity
-                idx = alive.index(prim) if prim in alive else 0
-                k = 1
-                while len(group) < min(width, len(alive)):
-                    cand = alive[(idx + k) % len(alive)]
-                    if cand not in group:
-                        group.append(cand)
-                    k += 1
-            groups.append(group)
-        return groups
-
-    def _allocate_ring(self, n_pages: int, blob_id: Optional[str],
-                       page_ids: Optional[Sequence[str]],
-                       width: int, strict: bool) -> List[List[DataProvider]]:
-        if not self.placement_pool():
+        if not alive:
             raise RuntimeError("no alive providers")
-        groups: List[List[DataProvider]] = []
+        keys: List[str] = []
         with self._lock:
             seq0 = self._alloc_seq.get(blob_id or "_", 0)
             self._alloc_seq[blob_id or "_"] = seq0 + n_pages
@@ -700,10 +617,10 @@ class ProviderManager:
                 key = f"{blob_id or '_'}#{seq0 + i}"
                 if page_ids is not None:
                     self._ring_keys[page_ids[i]] = key
-                groups.append(key)  # resolved below, outside the lock
-        eligible = {p.pid for p in self.placement_pool()}
+                keys.append(key)  # resolved below, outside the lock
+        eligible = {p.pid for p in alive}
         out: List[List[DataProvider]] = []
-        for key in groups:
+        for key in keys:
             owner_ids = self.ring.owners(key, width, eligible)
             if strict and len(owner_ids) < width:
                 raise EndpointDown(
@@ -716,20 +633,6 @@ class ProviderManager:
         return out
 
     # -- replicated page IO ---------------------------------------------------------
-    def store_page(
-        self,
-        group: Sequence[DataProvider],
-        page_id: str,
-        payload: bytes,
-        peer: Optional[str] = None,
-    ) -> List[str]:
-        """Store ``payload`` on every replica; return provider ids that hold it.
-        Single-page facade over :meth:`store_pages` (same batching,
-        failover and accounting)."""
-        locations, _done = self.store_pages([(group, page_id, payload)],
-                                            peer=peer)
-        return locations[0]
-
     def store_pages(
         self,
         puts: Sequence[Tuple[Sequence[DataProvider], str, bytes]],
@@ -750,9 +653,9 @@ class ProviderManager:
         publishes before its pages have arrived.  Under the wall clock
         the batches block as ordinary transfers and ``done_at`` is 0.0.
 
-        Failure semantics match :meth:`store_page` per page: a downed
-        endpoint just loses that replica; a page with *no* surviving
-        replica raises :class:`EndpointDown`.  Returns
+        Failure semantics are per page: a downed endpoint just loses
+        that replica; a page with *no* surviving replica raises
+        :class:`EndpointDown`.  Returns
         ``(locations, done_at)`` where ``locations[i]`` lists the
         provider ids now holding page ``i``.
         """
@@ -1584,9 +1487,7 @@ class ProviderManager:
         width = len(current) + extra
         targets = [pid for pid in
                    self.ring_owners(self.place_key(page_id), width)
-                   if pid not in current][:extra] if self.ring is not None \
-            else [p.pid for p in self.placement_pool()
-                  if p.pid not in current][:extra]
+                   if pid not in current][:extra]
         added = []
         for tpid in targets:
             with self._lock:

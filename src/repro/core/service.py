@@ -46,13 +46,11 @@ class BlobSeerService:
         *,
         data_replication: int = 1,
         meta_replication: int = 1,
-        placement: str = "ring",
         verify_digests: bool = False,
         wire: Optional[Wire] = None,
         wal_path: Optional[str] = None,
         spool_dir: Optional[str] = None,
         heartbeat_timeout: float = 5.0,
-        io_workers: int = 0,
         clock: Optional[Clock] = None,
         page_cache_bytes: int = DEFAULT_PAGE_CACHE_BYTES,
         read_prefetch_pages: int = 0,
@@ -109,7 +107,6 @@ class BlobSeerService:
         self.dedup = dedup
         self.pm = ProviderManager(
             self.wire,
-            strategy=placement,
             replication=data_replication,
             heartbeat_timeout=heartbeat_timeout,
             page_cache=self.page_cache,
@@ -124,7 +121,6 @@ class BlobSeerService:
             self.page_cache, self.wire)
         self.vm.add_gc_listener(self._on_retire_intent)
         self.read_prefetch_pages = read_prefetch_pages
-        self.io_workers = io_workers
         self._spool_dir = spool_dir
         self._spool_fsync = spool_fsync
         self._verify = verify_digests
@@ -249,8 +245,7 @@ class BlobSeerService:
         plane, in-flight reconfiguration state, migration counters."""
         pm_ctr = self.pm.rpc_counters()
         return {
-            "data_ring": sorted(self.pm.ring.nodes())
-            if self.pm.ring is not None else [],
+            "data_ring": sorted(self.pm.ring.nodes()),
             "data_draining": sorted(self.pm._draining),
             "data_departed": sorted(self.pm._departed),
             "meta_ring": sorted(self.dht.ring.nodes()),
@@ -298,7 +293,6 @@ class BlobSeerService:
         survive in the deployment counter ``dht_get_keys_cached``."""
         return BlobClient(
             self.vm, self.dht, self.pm, self.wire, name=name,
-            io_workers=self.io_workers,
             prefetch_pages=(self.read_prefetch_pages
                             if prefetch_pages is None else prefetch_pages),
             dedup_index=self.dedup_index,

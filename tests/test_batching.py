@@ -13,7 +13,7 @@ import pytest
 
 from repro.core import BlobSeerService, EndpointDown
 from repro.core import segment_tree as st
-from repro.core.blob import _NodeCache
+from repro.core.cache import NodeCache
 from repro.core.dht import MetadataDHT
 from repro.core.transport import Wire
 
@@ -228,14 +228,14 @@ def test_fetch_pages_raises_after_all_replicas_down():
 
 
 # ---------------------------------------------------------------------------
-# _NodeCache: batch-aware LRU
+# NodeCache: batch-aware LRU
 # ---------------------------------------------------------------------------
 
 
 def test_node_cache_lru_is_bounded_and_evicts_oldest(monkeypatch):
     dht = MetadataDHT(Wire(), 2)
-    cache = _NodeCache(dht)
-    monkeypatch.setattr(_NodeCache, "MAX_ENTRIES", 4)
+    cache = NodeCache(dht)
+    monkeypatch.setattr(NodeCache, "MAX_ENTRIES", 4)
     for i in range(6):
         cache.put(("k", i), {"v": i})
     assert len(cache._cache) == 4           # bounded, no clear-all
@@ -252,7 +252,7 @@ def test_node_cache_lru_is_bounded_and_evicts_oldest(monkeypatch):
 def test_node_cache_get_many_serves_hits_locally():
     dht = MetadataDHT(Wire(), 4)
     items = _fill(dht, 10)
-    cache = _NodeCache(dht)
+    cache = NodeCache(dht)
     keys = [k for k, _ in items]
     first = cache.get_many(keys)
     assert first == {k: v for k, v in items}
@@ -265,7 +265,7 @@ def test_node_cache_get_many_serves_hits_locally():
 
 
 def test_read_after_cache_eviction_still_correct(monkeypatch):
-    monkeypatch.setattr(_NodeCache, "MAX_ENTRIES", 8)
+    monkeypatch.setattr(NodeCache, "MAX_ENTRIES", 8)
     svc = BlobSeerService(n_providers=4, n_meta_shards=2)
     c = svc.client()
     bid = c.create(psize=16)

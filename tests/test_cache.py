@@ -90,10 +90,6 @@ def test_page_cache_invalidate_dooms_inflight_fill():
 
 
 def test_node_cache_promoted_and_counted():
-    # old import path still works (back-compat alias)
-    from repro.core.blob import _NodeCache
-    assert _NodeCache is NodeCache
-
     svc = BlobSeerService(n_providers=4, n_meta_shards=4)
     c = svc.client()
     bid = c.create(psize=PSIZE)
@@ -176,11 +172,12 @@ def test_cache_disabled_service_fetches_every_time():
 
 def test_single_flight_dedups_concurrent_readers():
     sim = Simulator(seed=5)
-    # pinned to the legacy pool strategy: the test's interleaving (a
-    # reader must arrive while another reader's fetch is in flight)
-    # depends on the page spread this seed produces under round_robin
-    svc = BlobSeerService(n_providers=8, n_meta_shards=4,
-                          wire=Wire(clock=sim), placement="round_robin")
+    # 4 providers, as in the benchmark deployments: the ring then gives
+    # each of the 4 pages its own provider, so the readers' fetches of
+    # different pages overlap and a later reader arrives while an
+    # earlier one's fetch of the same page is still in flight
+    svc = BlobSeerService(n_providers=4, n_meta_shards=4,
+                          wire=Wire(clock=sim))
     setup = svc.client("setup")
     bid = setup.create(psize=PSIZE)
     setup.append(bid, b"\xaa" * CHUNK)

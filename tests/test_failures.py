@@ -143,8 +143,7 @@ def test_full_service_restart_from_durable_state(tmp_path):
 
 
 def test_elastic_provider_join_rebalances():
-    svc = BlobSeerService(n_providers=2, n_meta_shards=2,
-                          placement="least_loaded")
+    svc = BlobSeerService(n_providers=2, n_meta_shards=2)
     c = svc.client()
     bid = c.create(psize=64)
     c.write(bid, b"x" * 64 * 64, 0)

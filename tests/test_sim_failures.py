@@ -54,7 +54,7 @@ def test_failure_schedule_is_deterministic():
 
 
 def test_appenders_survive_provider_death_with_replication():
-    """Writes keep publishing after a provider dies: store_page drops the
+    """Writes keep publishing after a provider dies: store_pages drops the
     dead replica, total order stays contiguous."""
     r = run_scenario(
         "appenders", 24, seed=2,
